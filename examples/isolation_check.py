@@ -2,14 +2,15 @@
 
 The paper's Kata/VPC guarantee, TPU-native: a tenant's compiled XLA program
 may only issue collectives whose replica groups stay inside its mesh slice.
-We carve two 4-device tenant slices out of an 8-device host mesh, compile a
-sharded train-ish program per tenant, and run MeshRouter.validate_isolation
-over the REAL optimized HLO — then show a cross-slice program being caught.
+We split the host's devices into two tenant slices, compile a sharded
+train-ish program per tenant, and run MeshRouter.validate_isolation over
+the REAL optimized HLO — then show a cross-slice program being caught.
+On the CPU the host is given 8 virtual devices; on a four-chip TPU host the
+slices are 2 chips each.
 
     PYTHONPATH=src python examples/isolation_check.py
 """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import numpy as np
 import jax
@@ -35,32 +36,48 @@ def tenant_program(mesh):
         ).lower(x, w).compile()
 
 
-def main():
-    devices = np.array(jax.devices())
-    slice_a = Mesh(devices[:4].reshape(2, 2), ("data", "model"))
-    slice_b = Mesh(devices[4:].reshape(2, 2), ("data", "model"))
-    full = Mesh(devices.reshape(2, 4), ("data", "model"))
+def check_isolation(devices) -> None:
+    """Validate two half-host tenant slices; raise unless a full-mesh
+    program is rejected against one of them. Needs a multiple of 4
+    devices (each slice is a (n/4, 2) data x model mesh)."""
+    n = len(devices)
+    if n % 4:
+        raise ValueError(f"need a multiple of 4 devices, have {n}")
+    devices = np.array(devices)
+    half = n // 2
+    slice_a = Mesh(devices[:half].reshape(half // 2, 2), ("data", "model"))
+    slice_b = Mesh(devices[half:].reshape(half // 2, 2), ("data", "model"))
+    full = Mesh(devices.reshape(2, half), ("data", "model"))
 
-    for name, mesh, allowed in (("tenant-A", slice_a, range(0, 4)),
-                                ("tenant-B", slice_b, range(4, 8))):
+    for name, mesh in (("tenant-A", slice_a), ("tenant-B", slice_b)):
         compiled = tenant_program(mesh)
         order = [d.id for d in mesh.devices.flatten()]   # logical -> physical
-        n = MeshRouter.validate_isolation(compiled.as_text(), allowed, order)
-        ids = sorted(order)
-        print(f"[{name}] slice devices {ids}: {n} collectives, "
-              f"all inside the slice OK")
+        n_coll = MeshRouter.validate_isolation(compiled.as_text(), order,
+                                               order)
+        if n_coll == 0:
+            raise AssertionError(f"{name}: program has no collectives")
+        print(f"[{name}] slice devices {sorted(order)}: {n_coll} "
+              f"collectives, all inside the slice OK")
 
     # a program spanning the full mesh must NOT validate against one slice
     compiled = tenant_program(full)
     order = [d.id for d in full.devices.flatten()]
+    slice_a_ids = [d.id for d in slice_a.devices.flatten()]
     try:
-        MeshRouter.validate_isolation(compiled.as_text(), range(0, 4), order)
-        raise SystemExit("ERROR: cross-slice program passed validation")
+        MeshRouter.validate_isolation(compiled.as_text(), slice_a_ids, order)
     except IsolationViolation as e:
         print(f"[full-mesh program vs tenant-A slice] correctly rejected: "
               f"{e}")
+    else:
+        raise AssertionError("cross-slice program passed validation")
+
+
+def main():
+    check_isolation(jax.devices())
     print("done")
 
 
 if __name__ == "__main__":
+    # virtual devices for a CPU run; read when jax first initialises
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     main()

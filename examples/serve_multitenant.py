@@ -40,8 +40,7 @@ def main():
         steady = fw.add_tenant("steady", weight=2)
         fleet.register_tenant(bursty)
         fleet.register_tenant(steady)
-        while fleet.live_replicas() < 2:
-            time.sleep(0.01)
+        fleet.wait_replicas(2, timeout=120)
         for u in fw.super_api.list("WorkUnit", "vc-serving"):
             print(f"[fleet] {u.metadata.name} scheduled on "
                   f"{u.status.node or '?'}")

@@ -1,70 +1,23 @@
-"""JAX version-compatibility shims (pinned environment: jax 0.4.37).
+"""Device-free mesh for the sharding planner, written for jax 0.9.
 
-Two API seams moved between jax releases:
-
-- ``AbstractMesh``: newer code writes ``AbstractMesh(shape, axis_names)``;
-  0.4.37 takes a single ``shape_tuple`` of ``(axis_name, size)`` pairs.
-  :func:`abstract_mesh` accepts the readable two-argument form and builds
-  whichever the installed jax understands.
-- ``shard_map``: newer code calls ``jax.shard_map(..., axis_names=...,
-  check_vma=...)``; 0.4.37 only has ``jax.experimental.shard_map.shard_map``
-  with ``auto=...`` (the complement of ``axis_names``) and ``check_rep=...``.
-  :func:`shard_map` presents the new keyword surface on either version.
-
-All sharding/model code should import these from here rather than touching
-``jax.shard_map`` / ``AbstractMesh`` directly.
+``jax.make_mesh`` and ``AbstractMesh`` there default to ``Explicit`` axes,
+under which ``with_sharding_constraint`` may only name ``Auto`` axes. The
+models shard by logical-axis rules (``repro.sharding.api``), so every mesh
+the repo builds has ``Auto`` axes (see also ``repro.launch.mesh``). Model
+code calls ``jax.shard_map`` directly.
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Sequence
+from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
 def abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Device-free mesh for planning/spec-generation (no jax device init).
-
-    ``abstract_mesh((16, 16), ("data", "model"))`` works on every supported
-    jax version regardless of the ``AbstractMesh`` constructor signature.
-    """
+    """Device-free mesh for planning/spec generation (no jax device init)."""
     if len(shape) != len(axes):
         raise ValueError(f"shape {tuple(shape)} and axes {tuple(axes)} "
                          f"must have equal length")
-    AbstractMesh = jax.sharding.AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axes, shape)))      # 0.4.37 shape_tuple
-    except (TypeError, ValueError):
-        return AbstractMesh(tuple(shape), tuple(axes))    # newer (shape, names)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs,
-              axis_names: Optional[Any] = None,
-              check_vma: Optional[bool] = None,
-              check_rep: Optional[bool] = None,
-              auto: Optional[Any] = None):
-    """``jax.shard_map`` with the new keyword surface on any jax version.
-
-    ``axis_names`` lists the axes the body handles manually; on old jax it is
-    translated to ``auto`` (its complement over the mesh axes). ``check_vma``
-    maps to legacy ``check_rep``.
-    """
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        vma = check_vma if check_vma is not None else check_rep
-        if vma is not None:
-            kw["check_vma"] = vma
-        return native(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-    from jax.experimental.shard_map import shard_map as legacy
-    kw = {}
-    rep = check_vma if check_vma is not None else check_rep
-    if rep is not None:
-        kw["check_rep"] = rep
-    if auto is not None:
-        kw["auto"] = frozenset(auto)
-    elif axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes),
+                                     axis_types=(AxisType.Auto,) * len(axes))

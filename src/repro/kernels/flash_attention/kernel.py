@@ -61,7 +61,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     l_ref[...] = l_prev * corr + p.sum(axis=-1)
     m_ref[...] = m_new
     pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0, 0],
-                             (((1,), (0,)), ((), ()))).astype(jnp.float32)
+                             (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
     acc_ref[...] = acc_ref[...] * corr[:, None] + pv
 
     @pl.when(ik == num_kv_blocks - 1)

@@ -5,7 +5,9 @@ Implementations:
 - "xla":     double-chunked online-softmax attention in pure jnp — the
              memory-efficient path used for CPU runs and 512-device dry-run
              lowering (same FLOPs and working-set shape as the TPU kernel);
-- "pallas":  the Pallas TPU kernel (kernel.py), interpret=True on CPU.
+- "pallas":  the Pallas TPU kernel (kernel.py), interpreted on the CPU
+             backend only (``repro.kernels.interpret_mode``);
+- "interpret": the Pallas kernel in the interpreter on any backend.
 
 ``impl=None`` auto-selects: pallas on TPU, xla elsewhere.
 """
@@ -17,6 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .ref import mha_ref
 
 _NEG_INF = -1e30
@@ -44,8 +47,7 @@ def mha(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         from .kernel import flash_attention
         return flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, scale=scale, q_offset=q_offset,
-                               interpret=(impl == "interpret"
-                                          or jax.default_backend() != "tpu"))
+                               interpret=interpret_mode(impl))
     raise ValueError(f"unknown attention impl: {impl}")
 
 
@@ -300,9 +302,7 @@ def decode_mha(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     if impl in ("pallas", "interpret"):
         from ..flash_decode.ops import flash_decode
         return flash_decode(q, k_cache, v_cache, lengths, window=window,
-                            softcap=softcap, scale=scale,
-                            interpret=(impl == "interpret"
-                                       or jax.default_backend() != "tpu"))
+                            softcap=softcap, scale=scale, impl=impl)
     B, _, H, D = q.shape
     acc, m, l = _decode_partials(q, k_cache, v_cache, lengths,
                                  pos_offset=None, window=window,
@@ -368,7 +368,6 @@ def _decode_mha_seq_sharded(q, k_cache, v_cache, lengths, *, rules, seq_axis,
                             window, softcap, scale, kv_chunk, impl):
     """Flash-decode: cache sequence-sharded over ``seq_axis``; partial
     softmax per shard; max-rescaled psum combine."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = rules.mesh
     B, _, H, D = q.shape
@@ -388,12 +387,12 @@ def _decode_mha_seq_sharded(q, k_cache, v_cache, lengths, *, rules, seq_axis,
         out = acc_g / (l_g[..., None] + 1e-30)
         return out.reshape(qi.shape[0], 1, H, D).astype(qi.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_part), P(batch_part, seq_axis),
                   P(batch_part, seq_axis), P(batch_part)),
         out_specs=P(batch_part),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_cache, v_cache, lengths)
 
 

@@ -3,8 +3,8 @@
 ``grouped_gemm(x_sorted, group_sizes, W)`` pads each expert's token segment
 to a multiple of block_m (building the block-aligned buffer + per-block
 expert ids), runs the kernel, and scatters back — the dropless-MoE building
-block. On CPU the kernel runs in interpret mode; ``impl="xla"`` uses
-jax.lax.ragged_dot.
+block. On the CPU backend the kernel runs in interpret mode;
+``impl="xla"`` uses jax.lax.ragged_dot.
 """
 from __future__ import annotations
 
@@ -13,12 +13,13 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
+
 
 def grouped_gemm(x: jnp.ndarray, group_sizes: jnp.ndarray, W: jnp.ndarray, *,
                  block_m: int = 128, impl: Optional[str] = None
                  ) -> jnp.ndarray:
     """x: [T, D] sorted by expert; group_sizes: [E]; W: [E, D, F] -> [T, F]."""
-    impl = impl or ("pallas" if jax.default_backend() == "tpu" else "pallas")
     if impl == "xla":
         return jax.lax.ragged_dot(x, W, group_sizes.astype(jnp.int32))
     if impl == "ref":
@@ -31,8 +32,7 @@ def grouped_gemm(x: jnp.ndarray, group_sizes: jnp.ndarray, W: jnp.ndarray, *,
     padded = -(-sizes // block_m) * block_m          # per-expert padded sizes
     p_offsets = jnp.cumsum(padded) - padded          # aligned segment starts
     offsets = jnp.cumsum(sizes) - sizes
-    Tp = T + E * (block_m - 1) - ((T - 1) % 1)       # safe upper bound
-    Tp = -(-T // block_m) * block_m + E * block_m
+    Tp = -(-T // block_m) * block_m + E * block_m    # safe upper bound
 
     # scatter rows into the block-aligned buffer
     tok = jnp.arange(T)
@@ -47,5 +47,5 @@ def grouped_gemm(x: jnp.ndarray, group_sizes: jnp.ndarray, W: jnp.ndarray, *,
 
     from .kernel import grouped_gemm_pallas
     ob = grouped_gemm_pallas(xb, block_expert, W, block_m=block_m,
-                             interpret=jax.default_backend() != "tpu")
+                             interpret=interpret_mode(impl))
     return ob[new_pos]

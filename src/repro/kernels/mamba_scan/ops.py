@@ -16,6 +16,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .ref import LOG_DECAY_CLAMP
 
 DEFAULT_CHUNK = 16
@@ -33,7 +34,7 @@ def mamba_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
         from .kernel import mamba_scan_pallas
         return mamba_scan_pallas(
             x, dt, A, B, C, D, state, chunk=chunk,
-            interpret=(impl == "interpret" or jax.default_backend() != "tpu"))
+            interpret=interpret_mode(impl))
     if impl == "ref":
         from .ref import mamba_scan_ref
         return mamba_scan_ref(x, dt, A, B, C, D, state)

@@ -18,6 +18,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
+
 LOG_DECAY_CLAMP = 5.0
 DEFAULT_CHUNK = 16
 
@@ -34,7 +36,7 @@ def rwkv6_scan(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         from .kernel import rwkv6_scan_pallas
         return rwkv6_scan_pallas(
             r, k, v, w, u, state, chunk=chunk,
-            interpret=(impl == "interpret" or jax.default_backend() != "tpu"))
+            interpret=interpret_mode(impl))
     if impl == "ref":
         from .ref import rwkv6_scan_ref
         return rwkv6_scan_ref(r, k, v, w, u, state)

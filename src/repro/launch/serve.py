@@ -33,6 +33,9 @@ def main(argv=None) -> int:
     from ..configs import get_config, reduced
     from ..models import init_params
     from ..serving import ContinuousBatcher, GenerationEngine
+    from .cache import enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

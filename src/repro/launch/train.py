@@ -39,6 +39,9 @@ def main(argv=None) -> int:
     from ..models import init_params
     from ..models.config import ShapeConfig
     from ..training import OptimizerConfig, make_opt_state, make_train_step
+    from .cache import enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

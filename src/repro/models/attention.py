@@ -155,8 +155,7 @@ def _out_proj(out, wo, cfg, compute_dtype):
         manual.add(fa)
     if bd:
         manual.update((bd,) if isinstance(bd, str) else bd)
-    from ..compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bd, None, axis), P(axis, fa)),
         out_specs=P(bd, axis, None),

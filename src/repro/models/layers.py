@@ -171,8 +171,7 @@ def _glu_seqpar(x, p, act, compute_dtype, rules, axis):
         manual.add(fa)
     if bd:
         manual.update((bd,) if isinstance(bd, str) else bd)
-    from ..compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bd, axis, None), P(fa, axis), P(fa, axis), P(axis, fa)),
         out_specs=P(bd, axis, None),

@@ -18,7 +18,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..sharding.api import active_rules, shard
 from .config import ModelConfig
@@ -134,7 +133,7 @@ def moe_apply(p: Dict[str, Any], x: jnp.ndarray, cfg: ModelConfig,
                              compute_dtype=compute_dtype)
     # remat: dispatch/expert intermediates ([E,C,D] buffers, [E,PC,F]
     # activations) are recomputed in the backward pass instead of saved.
-    out = jax.checkpoint(shard_map(
+    out = jax.checkpoint(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(b_part, s_part, None),
                   P(None, None),
@@ -142,7 +141,7 @@ def moe_apply(p: Dict[str, Any], x: jnp.ndarray, cfg: ModelConfig,
                   P(ep_part, None, None),
                   P(ep_part, None, None)),
         out_specs=P(b_part, s_part, None),
-        check_rep=False,
+        check_vma=False,
     ))(x, p["router"], p["w1"], p["wg"], p["w2"])
     return out.astype(x.dtype)
 

@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..core.agent import NodeAgent, Provider
 from ..core.apiserver import APIServer, TenantControlPlane
@@ -95,16 +95,20 @@ class EngineReplica:
     WRR scheduler, fused-admit them, fused-step while slots are active,
     report finished requests to the fleet. When idle it parks on the
     scheduler condvar (its own OS thread — never a cooperative task).
+    If admission or a step raises (a compile or device error), the loop
+    ends and hands the exception to ``on_failed``.
     """
 
     def __init__(self, key: str, node: str, engine: GenerationEngine,
                  scheduler: SlotScheduler,
-                 on_finished: Callable[[Request], None]):
+                 on_finished: Callable[[Request], None],
+                 on_failed: Callable[["EngineReplica", Exception], None]):
         self.key = key
         self.node = node
         self.engine = engine
         self.scheduler = scheduler
         self.on_finished = on_finished
+        self.on_failed = on_failed
         self._stop = threading.Event()
         self._thread = threading.Thread(
             target=self._drive, name=f"engine:{key}", daemon=True)
@@ -122,6 +126,12 @@ class EngineReplica:
         self._thread.join(timeout)
 
     def _drive(self) -> None:
+        try:
+            self._drive_loop()
+        except Exception as e:  # thread boundary: the fleet reports it
+            self.on_failed(self, e)
+
+    def _drive_loop(self) -> None:
         engine = self.engine
         while True:
             stopping = self._stop.is_set()
@@ -169,6 +179,7 @@ class ServingFleet(Controller):
         self._done_cv = threading.Condition(self._lock)
         self._uid = 0
         self.completed: Dict[int, Request] = {}
+        self.failures: List[Tuple[str, Exception]] = []   # (unit key, error)
         self.spawned = 0
         self.retired = 0
         # observability wiring (set by attach(): adopted from the framework)
@@ -261,6 +272,17 @@ class ServingFleet(Controller):
             self.completed[req.uid] = req
             self._done_cv.notify_all()
 
+    def _on_replica_failed(self, rep: EngineReplica, exc: Exception) -> None:
+        with self._done_cv:
+            self.failures.append((rep.key, exc))
+            self._done_cv.notify_all()
+
+    def _raise_failure_locked(self) -> None:
+        if self.failures:
+            key, exc = self.failures[0]
+            raise RuntimeError(f"engine replica {key} failed: {exc!r}") \
+                from exc
+
     def _trace_request(self, req: Request) -> None:
         """Synthesize the queue->admit->prefill->decode span tree from the
         request's timestamps — the hot decode loop never touches span
@@ -292,10 +314,12 @@ class ServingFleet(Controller):
     def wait_completed(self, n: int, timeout: float = 60.0
                        ) -> Dict[int, Request]:
         """Block until ``n`` requests completed (tests/benchmarks; never
-        called from a controller entry point)."""
+        called from a controller entry point). Raises as soon as a replica's
+        drive loop has failed, instead of waiting out the timeout."""
         deadline = time.monotonic() + timeout
         with self._done_cv:
             while len(self.completed) < n:
+                self._raise_failure_locked()
                 remaining = deadline - time.monotonic()
                 if remaining <= 0:
                     raise TimeoutError(
@@ -303,6 +327,26 @@ class ServingFleet(Controller):
                         f"after {timeout}s")
                 self._done_cv.wait(remaining)
             return dict(self.completed)
+
+    def wait_replicas(self, n: int, timeout: float = 60.0) -> None:
+        """Block until ``n`` replicas are live. Raises if an engine
+        WorkUnit failed (its node agent marks it ``Failed`` when
+        ``engine_factory`` raises), if a replica failed, or on timeout."""
+        deadline = time.monotonic() + timeout
+        while self.live_replicas() < n:
+            with self._lock:
+                self._raise_failure_locked()
+            if self.api is not None:
+                for u in self.api.list("WorkUnit", self.namespace,
+                                       copy=False):
+                    if u.status.phase == "Failed":
+                        raise RuntimeError(
+                            f"engine unit {u.metadata.key} failed: "
+                            f"{u.status.message}")
+            if time.monotonic() >= deadline:
+                raise TimeoutError(f"{self.live_replicas()}/{n} engine "
+                                   f"replicas live after {timeout}s")
+            time.sleep(0.01)
 
     def pop_completed(self) -> Dict[int, Request]:
         with self._lock:
@@ -318,7 +362,8 @@ class ServingFleet(Controller):
                 return
         engine = self.engine_factory()
         rep = EngineReplica(unit_key, node_name, engine, self.scheduler,
-                            self._on_request_finished)
+                            self._on_request_finished,
+                            self._on_replica_failed)
         start = False
         with self._lock:
             if unit_key not in self._replicas:

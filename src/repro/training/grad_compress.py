@@ -17,7 +17,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def init_error_state(grads_like: Any) -> Any:
@@ -40,7 +39,6 @@ def compressed_pod_mean(grads: Any, error: Any, mesh: Mesh,
     if pod_axis not in mesh.axis_names:
         return grads, error
     npod = mesh.shape[pod_axis]
-    other = frozenset(a for a in mesh.axis_names if a != pod_axis)
 
     def one(g, e):
         gf = g.astype(jnp.float32) + e
@@ -59,7 +57,7 @@ def compressed_pod_mean(grads: Any, error: Any, mesh: Mesh,
     def body(gtree, etree):
         return jax.tree.map(one, gtree, etree)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(), P()), out_specs=(P(), P()),
-                   check_rep=False, auto=other)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(), P()), out_specs=(P(), P()),
+                       axis_names={pod_axis}, check_vma=False)
     return fn(grads, error)

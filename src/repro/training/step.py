@@ -16,7 +16,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..models import decode_step as model_decode_step
 from ..models import loss_fn as model_loss_fn
@@ -30,9 +29,12 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
                     mesh: Optional[Mesh] = None,
                     grad_compress_pod: bool = False,
                     remat: bool = True,
-                    microbatches: int = 1,
-                    impl: Optional[str] = None) -> Callable:
+                    microbatches: int = 1) -> Callable:
     """Returns train_step(params, opt_state, batch) -> (params, opt, metrics).
+
+    The loss takes the XLA attention/scan paths on every backend: the
+    Pallas kernels are forward-only (no VJP), while the XLA flash path has
+    a custom VJP that recomputes per tile.
 
     ``microbatches`` > 1 splits the global batch and accumulates gradients
     over a lax.scan (activation memory / n at unchanged math). When
@@ -47,7 +49,8 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
         params = jax.tree.map(
             lambda p: p.astype(jnp.bfloat16)
             if p.dtype == jnp.float32 else p, params)
-        loss, aux = model_loss_fn(params, batch, cfg, remat=remat, impl=impl)
+        loss, aux = model_loss_fn(params, batch, cfg, remat=remat,
+                                  impl="xla")
         return loss, aux
 
     use_compress = (grad_compress_pod and mesh is not None
@@ -81,7 +84,6 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
 
     def compressed_grads(params, batch, ef):
         npod = mesh.shape["pod"]
-        other = frozenset(a for a in mesh.axis_names if a != "pod")
 
         def body(params, batch, ef):
             (loss, aux), grads = jax.value_and_grad(loss_of, has_aux=True)(
@@ -106,7 +108,7 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
             return loss, aux, gmean, ef_new
 
         batch_specs = jax.tree.map(lambda _: P("pod"), batch)
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), params), batch_specs,
                       jax.tree.map(lambda _: P(), ef)),
@@ -114,7 +116,7 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
                                                          "weight": 0}),
                        jax.tree.map(lambda _: P(), params),
                        jax.tree.map(lambda _: P(), ef)),
-            check_rep=False, auto=other)
+            axis_names={"pod"}, check_vma=False)
         loss, aux, grads, ef_new = fn(params, batch, ef)
         return loss, aux, grads, {"ef": ef_new}
 

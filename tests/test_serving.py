@@ -320,6 +320,60 @@ def test_fleet_bridge_replicas_metrics_and_scaledown(model):
         assert fleet.retired == 1
 
 
+class _FailingStepEngine:
+    """Engine stand-in whose decode step raises (a compile or device
+    error on the replica's drive thread)."""
+    slots = 1
+
+    def __init__(self):
+        self.req = None
+
+    def free_slots(self):
+        return [] if self.req is not None else [0]
+
+    def admit_many(self, reqs):
+        self.req = reqs[0] if reqs else None
+        return reqs
+
+    def active_slots(self):
+        return int(self.req is not None)
+
+    def step(self):
+        raise RuntimeError("decode step failed")
+
+
+def _fleet_rig(factory):
+    fleet = ServingFleet(factory, replicas=1, scan_interval=0.05)
+    fw = VirtualClusterFramework(num_nodes=1, scan_interval=0.0,
+                                 heartbeat_interval=3600)
+    fleet.attach(fw)
+    return fleet, fw
+
+
+def test_fleet_wait_completed_raises_when_drive_loop_fails():
+    fleet, fw = _fleet_rig(_FailingStepEngine)
+    with fw:
+        fleet.register_tenant("alpha")
+        fleet.wait_replicas(1, timeout=20)
+        fleet.submit("alpha", np.zeros(4, np.int32))
+        t0 = time.monotonic()
+        with pytest.raises(RuntimeError, match="decode step failed"):
+            fleet.wait_completed(1, timeout=30)
+        assert time.monotonic() - t0 < 1.0
+        assert len(fleet.failures) == 1
+
+
+def test_fleet_wait_replicas_raises_on_failed_engine_unit():
+    def factory():
+        raise MemoryError("engine does not fit")
+
+    fleet, fw = _fleet_rig(factory)
+    with fw:
+        with pytest.raises(RuntimeError, match="engine does not fit"):
+            fleet.wait_replicas(1, timeout=20)
+        assert fleet.live_replicas() == 0
+
+
 def test_agent_stops_deleted_units():
     """A DELETED WorkUnit reaches the node agent, which releases the
     provider's resources (and forgets the key so a recreate can run)."""

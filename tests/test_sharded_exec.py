@@ -10,10 +10,6 @@ import sys
 
 import pytest
 
-from repro.compat import shard_map  # noqa: F401 — the models' explicit-SP
-# shard_maps route through this shim; importing here fails fast (with a
-# readable error) if the installed jax satisfies neither API surface.
-
 SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -25,13 +21,13 @@ from repro.models.config import ShapeConfig
 from repro.sharding.api import use_rules
 from repro.sharding.planner import plan_for, train_shardings, serve_shardings
 from repro.training import OptimizerConfig, make_opt_state, make_train_step
-from repro.launch.specs import input_specs
+from repro.launch.mesh import make_test_mesh
 
 arch = %(arch)r
 cfg = reduced(REGISTRY[arch], d_model=64, n_heads=4,
               n_kv_heads=2 if REGISTRY[arch].n_kv_heads < REGISTRY[arch].n_heads else 4,
               head_dim=16, d_ff=128, vocab=256)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_test_mesh((2, 4))
 shape = ShapeConfig("t", 64, 8, "train")
 params = init_params(jax.random.PRNGKey(0), cfg)
 batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 64), 0, cfg.vocab),
