@@ -78,7 +78,10 @@ def _mha_xla_vjp(causal, window, softcap, scale, q_offset, q_chunk, kv_chunk):
         return out, (q, k, v, out, lse)
 
     def bwd(res, dout):
-        return _mha_bwd_impl(*res, dout, **kw)
+        # JAX traces a custom VJP's backward outside the transpose's name
+        # stack; the scope tells its ops from the forward's in a profile
+        with jax.named_scope("bwd"):
+            return _mha_bwd_impl(*res, dout, **kw)
 
     f.defvjp(fwd, bwd)
     return f

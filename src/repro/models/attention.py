@@ -58,64 +58,74 @@ def attn_apply(p: Dict[str, Any], x: jnp.ndarray, *, cfg: ModelConfig,
     B, S, D = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     window = cfg.sliding_window if kind == "l" else 0
-    q = dense(x, p["wq"], compute_dtype).reshape(B, S, H, hd)
-
     is_cross = kv_x is not None
-    if is_cross and cache is not None:
-        # decode-time cross attention: K/V precomputed at prefill
-        k, v = cache["k"], cache["v"]
-        new_cache = cache
-        q = shard(q, "batch", "attn_seq", "heads", None)
-        out = attn_ops.mha(q, k, v, causal=False, softcap=cfg.attn_softcap,
-                           impl=impl)
-    else:
-        src = kv_x if is_cross else x
-        Skv = src.shape[1]
-        k = dense(src, p["wk"], compute_dtype).reshape(B, Skv, KV, hd)
-        v = dense(src, p["wv"], compute_dtype).reshape(B, Skv, KV, hd)
-        if not is_cross and cfg.use_rope:
-            if positions is None:
-                positions = jnp.arange(S)
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
-        q = shard(q, "batch", "attn_seq", "heads", None)
-        k = shard(k, "batch", "kv_seq", "kv_heads", None)
-        v = shard(v, "batch", "kv_seq", "kv_heads", None)
-        if cache is None:
-            out = attn_ops.mha(q, k, v, causal=causal and not is_cross,
-                               window=window, softcap=cfg.attn_softcap,
-                               impl=impl)
-            new_cache = None
-        elif S == 1 and not is_cross:
-            # single-token decode: scatter new K/V at lengths-1, attend to cache
-            assert lengths is not None
-            idx = lengths - 1
-            upd = jax.vmap(
-                lambda c, kv1, i: jax.lax.dynamic_update_slice_in_dim(
-                    c, kv1, i, axis=0))
-            k_cache = upd(cache["k"], k[:, 0:1].astype(cache["k"].dtype)
-                          .reshape(B, 1, KV, hd), idx)
-            v_cache = upd(cache["v"], v[:, 0:1].astype(cache["v"].dtype)
-                          .reshape(B, 1, KV, hd), idx)
-            k_cache = shard(k_cache, "batch", "cache_seq", "kv_heads", None)
-            v_cache = shard(v_cache, "batch", "cache_seq", "kv_heads", None)
-            new_cache = {"k": k_cache, "v": v_cache}
-            out = attn_ops.decode_mha(q, k_cache, v_cache, lengths,
-                                      window=window, softcap=cfg.attn_softcap,
-                                      impl=impl)
+    with jax.named_scope("attn"):
+        with jax.named_scope("proj"):
+            q = dense(x, p["wq"], compute_dtype).reshape(B, S, H, hd)
+        if is_cross and cache is not None:
+            # decode-time cross attention: K/V precomputed at prefill
+            k, v = cache["k"], cache["v"]
+            new_cache = cache
+            with jax.named_scope("proj"):
+                q = shard(q, "batch", "attn_seq", "heads", None)
+            with jax.named_scope("core"):
+                out = attn_ops.mha(q, k, v, causal=False,
+                                   softcap=cfg.attn_softcap, impl=impl)
         else:
-            # prefill into an empty cache (S tokens at positions [0, S))
-            k_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), 0, axis=1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), 0, axis=1)
-            new_cache = {"k": k_cache, "v": v_cache}
-            out = attn_ops.mha(q, k, v, causal=True, window=window,
-                               softcap=cfg.attn_softcap, impl=impl)
+            src = kv_x if is_cross else x
+            Skv = src.shape[1]
+            with jax.named_scope("proj"):
+                k = dense(src, p["wk"], compute_dtype).reshape(B, Skv, KV, hd)
+                v = dense(src, p["wv"], compute_dtype).reshape(B, Skv, KV, hd)
+                if not is_cross and cfg.use_rope:
+                    if positions is None:
+                        positions = jnp.arange(S)
+                    q = rope(q, positions, cfg.rope_theta)
+                    k = rope(k, positions, cfg.rope_theta)
+                q = shard(q, "batch", "attn_seq", "heads", None)
+                k = shard(k, "batch", "kv_seq", "kv_heads", None)
+                v = shard(v, "batch", "kv_seq", "kv_heads", None)
+            if cache is None:
+                with jax.named_scope("core"):
+                    out = attn_ops.mha(q, k, v, causal=causal and not is_cross,
+                                       window=window, softcap=cfg.attn_softcap,
+                                       impl=impl)
+                new_cache = None
+            elif S == 1 and not is_cross:
+                # single-token decode: scatter new K/V at lengths-1, attend
+                # to cache
+                assert lengths is not None
+                idx = lengths - 1
+                upd = jax.vmap(
+                    lambda c, kv1, i: jax.lax.dynamic_update_slice_in_dim(
+                        c, kv1, i, axis=0))
+                k_cache = upd(cache["k"], k[:, 0:1].astype(cache["k"].dtype)
+                              .reshape(B, 1, KV, hd), idx)
+                v_cache = upd(cache["v"], v[:, 0:1].astype(cache["v"].dtype)
+                              .reshape(B, 1, KV, hd), idx)
+                k_cache = shard(k_cache, "batch", "cache_seq", "kv_heads", None)
+                v_cache = shard(v_cache, "batch", "cache_seq", "kv_heads", None)
+                new_cache = {"k": k_cache, "v": v_cache}
+                with jax.named_scope("core"):
+                    out = attn_ops.decode_mha(q, k_cache, v_cache, lengths,
+                                              window=window,
+                                              softcap=cfg.attn_softcap,
+                                              impl=impl)
+            else:
+                # prefill into an empty cache (S tokens at positions [0, S))
+                k_cache = jax.lax.dynamic_update_slice_in_dim(
+                    cache["k"], k.astype(cache["k"].dtype), 0, axis=1)
+                v_cache = jax.lax.dynamic_update_slice_in_dim(
+                    cache["v"], v.astype(cache["v"].dtype), 0, axis=1)
+                new_cache = {"k": k_cache, "v": v_cache}
+                with jax.named_scope("core"):
+                    out = attn_ops.mha(q, k, v, causal=True, window=window,
+                                       softcap=cfg.attn_softcap, impl=impl)
 
-    out = shard(out, "batch", "attn_seq", "heads", None)
-    out = out.reshape(B, S, H * hd)
-    proj = _out_proj(out, p["wo"], cfg, compute_dtype)
+        with jax.named_scope("proj"):
+            out = shard(out, "batch", "attn_seq", "heads", None)
+            out = out.reshape(B, S, H * hd)
+            proj = _out_proj(out, p["wo"], cfg, compute_dtype)
     return proj, new_cache
 
 

@@ -25,13 +25,14 @@ def truncated_normal(key, shape, dtype=jnp.float32, stddev=0.02):
 def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6,
              zero_centered: bool = False) -> jnp.ndarray:
     """RMSNorm in fp32, cast back to x.dtype. gemma2 uses (1 + scale)."""
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    xn = xf * jax.lax.rsqrt(var + eps)
-    s = scale.astype(jnp.float32)
-    if zero_centered:
-        s = 1.0 + s
-    return (xn * s).astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        xn = xf * jax.lax.rsqrt(var + eps)
+        s = scale.astype(jnp.float32)
+        if zero_centered:
+            s = 1.0 + s
+        return (xn * s).astype(x.dtype)
 
 
 def group_norm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
@@ -121,10 +122,11 @@ def glu(x: jnp.ndarray, p: Dict[str, Any], act: str = "silu",
     rules = active_rules()
     seq_ax = rules.bindings.get("seq") if rules is not None else None
     mlp_ax = rules.bindings.get("mlp") if rules is not None else None
-    if (rules is not None and isinstance(seq_ax, str) and seq_ax == mlp_ax
-            and "b" not in p["wi"] and x.shape[1] > 1):
-        return _glu_seqpar(x, p, act, compute_dtype, rules, seq_ax)
-    return _glu_plain(x, p, act, compute_dtype)
+    with jax.named_scope("mlp"):
+        if (rules is not None and isinstance(seq_ax, str) and seq_ax == mlp_ax
+                and "b" not in p["wi"] and x.shape[1] > 1):
+            return _glu_seqpar(x, p, act, compute_dtype, rules, seq_ax)
+        return _glu_plain(x, p, act, compute_dtype)
 
 
 def _glu_plain(x, p, act, compute_dtype):
@@ -191,11 +193,12 @@ def embed_axes() -> Dict[str, Any]:
 
 def embed(tokens: jnp.ndarray, p: Dict[str, Any], *,
           scale_by_dim: bool = False, compute_dtype=jnp.bfloat16) -> jnp.ndarray:
-    tbl = p["table"].astype(compute_dtype)
-    x = jnp.take(tbl, tokens, axis=0)
-    if scale_by_dim:  # gemma embedding scaling
-        x = x * jnp.asarray(tbl.shape[-1] ** 0.5, compute_dtype)
-    return shard(x, "batch", "seq", "embed")
+    with jax.named_scope("embed"):
+        tbl = p["table"].astype(compute_dtype)
+        x = jnp.take(tbl, tokens, axis=0)
+        if scale_by_dim:  # gemma embedding scaling
+            x = x * jnp.asarray(tbl.shape[-1] ** 0.5, compute_dtype)
+        return shard(x, "batch", "seq", "embed")
 
 
 # ----------------------------------------------------------------- chunked loss
@@ -231,18 +234,22 @@ def chunked_softmax_xent(h: jnp.ndarray, vocab_w: jnp.ndarray,
     wv = vocab_w.astype(compute_dtype)
 
     def body(carry, inp):
-        loss_sum, w_sum = carry
-        hc, lc, mc = inp
-        logits = (hc.astype(compute_dtype) @ wv).astype(jnp.float32)
-        if final_softcap > 0.0:
-            logits = jnp.tanh(logits / final_softcap) * final_softcap
-        if 0 < valid_vocab < V:     # padded vocab rows stay out of the lse
-            logits = jnp.where(jnp.arange(V) < valid_vocab, logits, -1e30)
-        logits = shard(logits, "batch", "act_seq", "vocab")
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        lab = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
-        loss = (lse - lab) * mc
-        return (loss_sum + loss.sum(), w_sum + mc.sum()), None
+        # named inside the body too: JAX hoists the vocab mask, which reads
+        # no input, out of the scan and away from the caller's scope
+        with jax.named_scope("loss"):
+            loss_sum, w_sum = carry
+            hc, lc, mc = inp
+            logits = (hc.astype(compute_dtype) @ wv).astype(jnp.float32)
+            if final_softcap > 0.0:
+                logits = jnp.tanh(logits / final_softcap) * final_softcap
+            if 0 < valid_vocab < V:     # padded rows stay out of the lse
+                logits = jnp.where(jnp.arange(V) < valid_vocab, logits,
+                                   -1e30)
+            logits = shard(logits, "batch", "act_seq", "vocab")
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            lab = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
+            loss = (lse - lab) * mc
+            return (loss_sum + loss.sum(), w_sum + mc.sum()), None
 
     # remat: the [B, c, V] logits are recomputed in the backward pass —
     # the whole point of chunking is never holding more than one chunk.

@@ -136,6 +136,26 @@ def _init_enc_block(key, cfg: ModelConfig) -> Dict[str, Any]:
             "ffn": init_glu(k2, cfg.d_model, cfg.d_ff)}
 
 
+# the scope of the layer that reads each top-level parameter entry
+PARAM_SCOPES = {"embed": "embed", "frontend_proj": "frontend",
+                "blocks": "blocks", "enc_blocks": "blocks",
+                "final_norm": "norm", "enc_final_norm": "norm",
+                "lm_head": "loss"}
+
+
+def cast_params(params: Dict[str, Any]) -> Dict[str, Any]:
+    """``params`` with float32 leaves cast to bfloat16, each top-level
+    entry under the scope of the layer that reads it, so that the cast and
+    its transpose in a train step are named with that layer. Entries go in
+    sorted order, the order ``jax.tree.map`` casts them in."""
+    out = {}
+    for k in sorted(params):
+        with jax.named_scope(PARAM_SCOPES[k]):
+            out[k] = jax.tree.map(lambda p: p.astype(jnp.bfloat16)
+                                  if p.dtype == jnp.float32 else p, params[k])
+    return out
+
+
 def param_axes(cfg: ModelConfig) -> Dict[str, Any]:
     axes: Dict[str, Any] = {
         "embed": embed_axes(),
@@ -335,24 +355,17 @@ def forward(params, cfg: ModelConfig, *, tokens=None, positions=None,
     x = embed(tokens, params["embed"], scale_by_dim=cfg.embed_scale,
               compute_dtype=compute_dtype)
     if cfg.frontend == "vit_stub" and patches is not None:
-        pe = patches.astype(compute_dtype) @ params["frontend_proj"]["w"].astype(
-            compute_dtype)
-        x = jnp.concatenate([pe, x[:, patches.shape[1]:]], axis=1)
-        x = shard(x, "batch", "seq", "embed")
+        with jax.named_scope("frontend"):
+            pe = patches.astype(compute_dtype) @ params["frontend_proj"][
+                "w"].astype(compute_dtype)
+            x = jnp.concatenate([pe, x[:, patches.shape[1]:]], axis=1)
+            x = shard(x, "batch", "seq", "embed")
     enc_out = None
     if cfg.is_encdec and frames is not None:
         enc_out = _encode(params, frames, cfg, impl, compute_dtype)
 
     B, S, _ = x.shape
-    if positions is None:
-        positions = (jnp.arange(S) if lengths is None or S > 1
-                     else (lengths - 1)[:, None])
     has_cache = cache is not None
-
-    body_fn = functools.partial(
-        _block_body, cfg=cfg, positions=positions, lengths=lengths,
-        enc_out=enc_out, has_cache=has_cache, impl=impl,
-        compute_dtype=compute_dtype)
 
     def scan_body(carry, xs):
         p_block, c_block = xs
@@ -363,27 +376,35 @@ def forward(params, cfg: ModelConfig, *, tokens=None, positions=None,
         scan_body = jax.checkpoint(
             scan_body, policy=jax.checkpoint_policies.nothing_saveable)
 
-    c_in = cache if has_cache else jax.tree.map(lambda _: 0, params["blocks"])
-    if not has_cache:
-        # dummy xs aligned with blocks; body ignores it
-        c_in = {"_": jnp.zeros((cfg.n_blocks,), jnp.float32)}
-    x, new_cache = jax.lax.scan(scan_body, x, (params["blocks"], c_in))
+    with jax.named_scope("blocks"):
+        if positions is None:
+            positions = (jnp.arange(S) if lengths is None or S > 1
+                         else (lengths - 1)[:, None])
+        body_fn = functools.partial(
+            _block_body, cfg=cfg, positions=positions, lengths=lengths,
+            enc_out=enc_out, has_cache=has_cache, impl=impl,
+            compute_dtype=compute_dtype)
+        c_in = cache if has_cache else {
+            # dummy xs aligned with blocks; body ignores it
+            "_": jnp.zeros((cfg.n_blocks,), jnp.float32)}
+        x, new_cache = jax.lax.scan(scan_body, x, (params["blocks"], c_in))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.zero_centered_norm)
     return x, (new_cache if has_cache else None)
 
 
 def logits_head(params, cfg: ModelConfig, h: jnp.ndarray,
                 compute_dtype=jnp.bfloat16) -> jnp.ndarray:
-    w = (params["embed"]["table"].T if cfg.tie_embeddings
-         else params["lm_head"]["w"])
-    logits = (h.astype(compute_dtype) @ w.astype(compute_dtype)).astype(
-        jnp.float32)
-    if cfg.final_softcap > 0:
-        logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
-    if cfg.padded_vocab != cfg.vocab:   # mask padding rows out of the softmax
-        logits = jnp.where(jnp.arange(cfg.padded_vocab) < cfg.vocab,
-                           logits, -1e30)
-    return shard(logits, "batch", "act_seq", "vocab")
+    with jax.named_scope("loss"):
+        w = (params["embed"]["table"].T if cfg.tie_embeddings
+             else params["lm_head"]["w"])
+        logits = (h.astype(compute_dtype) @ w.astype(compute_dtype)).astype(
+            jnp.float32)
+        if cfg.final_softcap > 0:
+            logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
+        if cfg.padded_vocab != cfg.vocab:   # mask padding out of the softmax
+            logits = jnp.where(jnp.arange(cfg.padded_vocab) < cfg.vocab,
+                               logits, -1e30)
+        return shard(logits, "batch", "act_seq", "vocab")
 
 
 def loss_fn(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig, *,
@@ -394,17 +415,18 @@ def loss_fn(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig, *,
     h, _ = forward(params, cfg, tokens=tokens,
                    frames=batch.get("frames"), patches=batch.get("patches"),
                    remat=remat, impl=impl, compute_dtype=compute_dtype)
-    labels = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))
-    mask = batch.get("mask")
-    if mask is None:
-        mask = jnp.ones(tokens.shape, jnp.float32)
-    mask = mask.at[:, -1].set(0.0)
-    w = (params["embed"]["table"].T if cfg.tie_embeddings
-         else params["lm_head"]["w"])
-    loss_sum, w_sum = chunked_softmax_xent(
-        h, w, labels, mask=mask, final_softcap=cfg.final_softcap,
-        valid_vocab=cfg.vocab, compute_dtype=compute_dtype)
-    loss = loss_sum / jnp.maximum(w_sum, 1.0)
+    with jax.named_scope("loss"):
+        labels = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))
+        mask = batch.get("mask")
+        if mask is None:
+            mask = jnp.ones(tokens.shape, jnp.float32)
+        mask = mask.at[:, -1].set(0.0)
+        w = (params["embed"]["table"].T if cfg.tie_embeddings
+             else params["lm_head"]["w"])
+        loss_sum, w_sum = chunked_softmax_xent(
+            h, w, labels, mask=mask, final_softcap=cfg.final_softcap,
+            valid_vocab=cfg.vocab, compute_dtype=compute_dtype)
+        loss = loss_sum / jnp.maximum(w_sum, 1.0)
     return loss, {"loss_sum": loss_sum, "weight": w_sum}
 
 

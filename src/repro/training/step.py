@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..models import cast_params
 from ..models import decode_step as model_decode_step
 from ..models import loss_fn as model_loss_fn
 from ..models import prefill as model_prefill
@@ -46,9 +47,7 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
     def loss_of(params, batch):
         # cast fp32 masters to bf16 BEFORE use: FSDP all-gathers then move
         # bf16, halving gather bytes and buffers
-        params = jax.tree.map(
-            lambda p: p.astype(jnp.bfloat16)
-            if p.dtype == jnp.float32 else p, params)
+        params = cast_params(params)
         loss, aux = model_loss_fn(params, batch, cfg, remat=remat,
                                   impl="xla")
         return loss, aux
@@ -126,8 +125,9 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
                 params, batch, opt_state["ef"])
         else:
             loss, aux, grads, extra = plain_grads(params, batch)
-        new_params, new_opt, metrics = adamw_update(
-            opt_cfg, params, grads, opt_state)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, metrics = adamw_update(
+                opt_cfg, params, grads, opt_state)
         new_opt.update(extra)
         metrics = dict(metrics)
         metrics["loss"] = loss
