@@ -2,14 +2,29 @@
 
 Implementations:
 - "ref":     naive materialized softmax (oracle; small shapes only);
-- "xla":     double-chunked online-softmax attention in pure jnp — the
-             memory-efficient path used for CPU runs and 512-device dry-run
-             lowering (same FLOPs and working-set shape as the TPU kernel);
-- "pallas":  the Pallas TPU kernel (kernel.py), interpreted on the CPU
-             backend only (``repro.kernels.interpret_mode``);
-- "interpret": the Pallas kernel in the interpreter on any backend.
+- "xla":     double-chunked online-softmax attention in pure jnp with a
+             flash-style custom VJP — the path for CPU runs, 512-device
+             dry-run lowering, and sequence-sharded attention;
+- "pallas":  the Pallas TPU kernels (kernel.py): forward, and a backward
+             of dq and dk/dv kernels under a custom VJP, so training and
+             serving run the same forward; interpreted on the CPU backend
+             only (``repro.kernels.interpret_mode``);
+- "interpret": the Pallas kernels in the interpreter on any backend.
 
 ``impl=None`` auto-selects: pallas on TPU, xla elsewhere.
+
+Both paths feed the MXU q, k, v in their own dtype (bf16 from the models),
+accumulate in f32 and keep the scale, soft-cap and softmax statistics in
+f32. The Pallas backward covers soft-capping and sliding windows itself:
+``_mha_bwd_impl`` serves the XLA path alone.
+
+Under active sharding rules the Pallas kernels run per chip inside a
+``shard_map`` over the batch and head axes, when the rules leave the
+sequence unsharded (``attn_seq``, ``kv_seq`` unbound) and bind ``heads``
+and ``kv_heads`` alike: each chip then holds whole sequences of its heads
+and their kv heads, and no collective runs around the call. Otherwise
+(the ``context`` strategy, or kv heads replicated under sharded q heads)
+the XLA path runs, which the partitioner can split along the sequence.
 """
 from __future__ import annotations
 
@@ -39,16 +54,41 @@ def mha(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     if impl == "ref":
         return mha_ref(q, k, v, causal=causal, window=window, softcap=softcap,
                        scale=scale, q_offset=q_offset)
+    if impl in ("pallas", "interpret"):
+        from .kernel import flash_attention
+        fn = functools.partial(
+            flash_attention, causal=causal, window=window, softcap=softcap,
+            scale=scale, q_offset=q_offset, interpret=interpret_mode(impl))
+        from ...sharding.api import active_rules
+        rules = active_rules()
+        if rules is None:
+            return fn(q, k, v)
+        specs = _head_parallel_specs(rules)
+        if specs is not None:
+            q_spec, kv_spec = specs
+            return jax.shard_map(
+                fn, mesh=rules.mesh, in_specs=(q_spec, kv_spec, kv_spec),
+                out_specs=q_spec, check_vma=False)(q, k, v)
+        impl = "xla"
     if impl == "xla":
         return _mha_xla(q, k, v, causal=causal, window=window, softcap=softcap,
                         scale=scale, q_offset=q_offset,
                         q_chunk=q_chunk, kv_chunk=kv_chunk)
-    if impl in ("pallas", "interpret"):
-        from .kernel import flash_attention
-        return flash_attention(q, k, v, causal=causal, window=window,
-                               softcap=softcap, scale=scale, q_offset=q_offset,
-                               interpret=interpret_mode(impl))
     raise ValueError(f"unknown attention impl: {impl}")
+
+
+def _head_parallel_specs(rules):
+    """(q spec, k/v spec) under which each chip attends whole sequences of
+    its own heads, or None where the rules shard a sequence, or shard q
+    heads and kv heads differently, or a caller's ``shard_map`` already
+    holds mesh axes."""
+    b = rules.bindings
+    if (b.get("attn_seq") is not None or b.get("kv_seq") is not None
+            or b.get("heads") != b.get("kv_heads")
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return None
+    return (rules.spec(("batch", None, "heads", None)),
+            rules.spec(("batch", None, "kv_heads", None)))
 
 
 def _mha_xla(q, k, v, *, causal, window, softcap, scale, q_offset,

@@ -33,9 +33,11 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
                     microbatches: int = 1) -> Callable:
     """Returns train_step(params, opt_state, batch) -> (params, opt, metrics).
 
-    The loss takes the XLA attention/scan paths on every backend: the
-    Pallas kernels are forward-only (no VJP), while the XLA flash path has
-    a custom VJP that recomputes per tile.
+    Attention takes ``ops.mha``'s own choice: on a TPU the Pallas flash
+    kernels, whose custom VJP runs dq and dk/dv kernels that recompute p
+    per tile, each chip on its own heads under a ``shard_map`` where the
+    sharding rules leave the sequence whole; elsewhere, and under a
+    sequence-sharded (``context``) layout, the XLA flash path.
 
     ``microbatches`` > 1 splits the global batch and accumulates gradients
     over a lax.scan (activation memory / n at unchanged math). When
@@ -44,12 +46,16 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig, *,
     carries an extra "ef" residual tree.
     """
 
+    # the recurrent scans' Pallas kernels have no VJP: a model with such
+    # layers keeps every kernel on its XLA path
+    impl = "xla" if set(cfg.layer_pattern) & {"m", "r"} else None
+
     def loss_of(params, batch):
         # cast fp32 masters to bf16 BEFORE use: FSDP all-gathers then move
         # bf16, halving gather bytes and buffers
         params = cast_params(params)
         loss, aux = model_loss_fn(params, batch, cfg, remat=remat,
-                                  impl="xla")
+                                  impl=impl)
         return loss, aux
 
     use_compress = (grad_compress_pod and mesh is not None

@@ -6,7 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention.kernel import flash_attention
+from repro.kernels.flash_attention.kernel import (flash_attention,
+                                                  flash_attention_fwd)
 from repro.kernels.flash_attention.ops import _mha_xla, decode_mha
 from repro.kernels.flash_attention.ref import mha_ref
 from repro.kernels.flash_decode.kernel import flash_decode_pallas
@@ -92,6 +93,70 @@ def test_flash_attention_grads_vs_ref(case):
     for a, b in zip(gx, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-5, rtol=5e-4)
+
+
+# the Pallas VJP: ATTN_CASES in 32-row blocks (S=100: the block does not
+# divide S), and one case in the default blocks (512 rows: S=600 padded)
+PALLAS_GRAD_CASES = [(c, 32) for c in ATTN_CASES] + [
+    ((1, 600, 600, 4, 2, 32, True, 0, 0.0), None)]
+
+
+def _pallas_case(case, block):
+    B, S, T, H, KV, D, causal, window, softcap = case
+    ks = jax.random.split(KEY, 4)
+    q = rand(ks[0], (B, S, H, D), jnp.float32)
+    k = rand(ks[1], (B, T, KV, D), jnp.float32)
+    v = rand(ks[2], (B, T, KV, D), jnp.float32)
+    dout = rand(ks[3], (B, S, H, D), jnp.float32)
+    kw = dict(causal=causal, window=window, softcap=softcap,
+              q_offset=T - S if causal else 0)
+    return (q, k, v, dout), kw, dict(block_q=block, block_k=block,
+                                     interpret=True)
+
+
+@pytest.mark.parametrize("case,block", PALLAS_GRAD_CASES)
+def test_flash_attention_pallas_grads_vs_ref(case, block):
+    (q, k, v, dout), kw, pk = _pallas_case(case, block)
+
+    def loss_p(q, k, v):
+        return (flash_attention(q, k, v, **kw, **pk) * dout).sum()
+
+    def loss_r(q, k, v):
+        return (mha_ref(q, k, v, **kw) * dout).sum()
+
+    gp = jax.grad(loss_p, (0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_r, (0, 1, 2))(q, k, v)
+    for a, b in zip(gp, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=5e-4)
+
+
+@pytest.mark.parametrize("case,block", PALLAS_GRAD_CASES)
+def test_flash_attention_pallas_lse_vs_ref(case, block):
+    """The residual the backward reads: lse = log sum_k exp(s), f32."""
+    (q, k, v, _), kw, pk = _pallas_case(case, block)
+    B, S, H, D = q.shape
+    T, KV = k.shape[1], k.shape[2]
+    out, lse = flash_attention_fwd(q, k, v, with_lse=True, **kw, **pk)
+    s = jnp.einsum("bsngd,btnd->bngst", q.reshape(B, S, KV, H // KV, D),
+                   k) * D ** -0.5
+    if kw["softcap"] > 0.0:
+        s = jnp.tanh(s / kw["softcap"]) * kw["softcap"]
+    qpos = jnp.arange(S)[:, None] + kw["q_offset"]
+    kpos = jnp.arange(T)[None, :]
+    mask = kpos < T
+    if kw["causal"]:
+        mask &= kpos <= qpos
+    if kw["window"]:
+        mask &= kpos > qpos - kw["window"]
+    ref = jax.nn.logsumexp(jnp.where(mask, s, -jnp.inf), axis=-1)
+    assert lse.shape == (B, H, 1, S) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse[:, :, 0]),
+                               np.asarray(ref.reshape(B, H, S)),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(mha_ref(q, k, v, **kw)),
+                               atol=2e-5, rtol=2e-5)
 
 
 DECODE_CASES = [
