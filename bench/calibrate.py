@@ -10,9 +10,13 @@ chip and never by the benchmark's own runs.
         --control-seeds 1,2 --fault-seeds 1,2
 
 ``sweep`` runs the cell's traffic at each offered rate, in one process,
-and prints how the slot scheduler's wait grows over the window: the knee
-is the highest rate at which the wait of the last third of the requests
-stays near that of the first third; each rate also prints every tail at
+and prints how the slot scheduler's wait grows over the window. A rate
+holds where every request finishes within the drain and the mean wait of
+the last third of the requests (by due time) is at most twice that of the
+first third, or under ``BACKLOG_FLOOR_S``: a request that waits less than
+that waited for the decode steps in flight, not for a slot, so the ratio
+of two such waits says nothing of a backlog. The knee is the highest rate
+that holds on every seed swept. Each rate also prints every tail at
 several percentiles and the run's checks. ``limits`` runs the cell on
 each seed and prints the widest logit gap of the served tokens under the
 reference, and, for the control seeds (in ``sweep`` too), the widest gap
@@ -45,6 +49,8 @@ sys.path.insert(0, str(ROOT))
 from bench.harness.common import (check_devices, enable_cache, log,  # noqa: E402
                                   percentile)
 from bench.run import find_cell, load_benchmark  # noqa: E402
+
+BACKLOG_FLOOR_S = 0.25
 
 
 def _control_hook(conf, mix, serve, controls):
@@ -82,6 +88,7 @@ def _sweep(args, conf, mix, devices, serve) -> None:
         reqs = sorted(run.requests, key=lambda q: q["due"])
         wait = [((q["received"] or run.deadline) - q["due"]) for q in reqs]
         third = max(1, len(wait) // 3)
+        first, last = sum(wait[:third]) / third, sum(wait[-third:]) / third
         toks = sum(len(q["tokens"]) for q in reqs)
         out = {"rate_rps": rate, "seed": seed, "requests": len(reqs),
                **{f"ttft_p{q}_ms": percentile(serve.ttft_ms(run), q)
@@ -90,8 +97,10 @@ def _sweep(args, conf, mix, devices, serve) -> None:
                   for q in serve.PERCENTILES},
                "propagation_p95_ms": percentile(serve.propagation_ms(run),
                                                 95),
-               "wait_first_third_s": sum(wait[:third]) / third,
-               "wait_last_third_s": sum(wait[-third:]) / third,
+               "wait_first_third_s": first,
+               "wait_last_third_s": last,
+               "holds": all(q["finished"] for q in reqs) and (
+                   last <= 2 * first or last < BACKLOG_FLOOR_S),
                "tokens_per_s": toks / (max(q["stamps"][-1] for q in reqs
                                            if q["stamps"]) - run.w0),
                "setup_s": run.setup_s,

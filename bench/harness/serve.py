@@ -118,6 +118,35 @@ class EngineProxy:
         return finished
 
 
+class GcPauses:
+    """Pauses of Python's cyclic collector while registered in
+    ``gc.callbacks``: (generation, start, seconds) on the harness clock.
+    Logged, so that a stall of the host in the window can be told apart
+    from one of the device."""
+
+    def __init__(self):
+        self.pauses: List[Tuple[int, float, float]] = []
+        self._t0: Optional[float] = None
+
+    def __call__(self, phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            self._t0 = now()
+        elif self._t0 is not None:
+            self.pauses.append((int(info["generation"]), self._t0,
+                                now() - self._t0))
+            self._t0 = None
+
+    def summary(self, w0: float) -> str:
+        if not self.pauses:
+            return "no collections"
+        gen, t, longest = max(self.pauses, key=lambda p: p[2])
+        full = sum(1 for p in self.pauses if p[0] == 2)
+        return (f"{len(self.pauses)} collections ({full} of generation 2), "
+                f"{sum(p[2] for p in self.pauses) * 1e3:.3f} ms in all; "
+                f"longest {longest * 1e3:.3f} ms (generation {gen}, at "
+                f"+{t - w0:.3f} s)")
+
+
 # ---------------------------------------------------------- load generators
 
 class OpenLoop(threading.Thread):
@@ -480,6 +509,7 @@ def run(*, conf: Dict[str, Any], mix: Dict[str, Any], seed: int,
     fleet.meter = None
     weights = {t["name"]: t["weight"] for t in mix["tenants"]}
     load = gen = None
+    pauses = GcPauses()
     try:
         fw.start()
         planes = {}
@@ -500,6 +530,7 @@ def run(*, conf: Dict[str, Any], mix: Dict[str, Any], seed: int,
         meter0 = _meter_totals(fw)
         compiles0 = clock.count
         setup_s = w0 - t_process
+        gc.callbacks.append(pauses)
         gen.start()
         load.start()
         prof = None
@@ -514,6 +545,7 @@ def run(*, conf: Dict[str, Any], mix: Dict[str, Any], seed: int,
             time.sleep(max(0.0, t_on + span - now()))
             xplane = prof.stop()
         time.sleep(max(0.0, w1 - now()))
+        gc.callbacks.remove(pauses)
         window_compiles = clock.count - compiles0
         meter1 = _meter_totals(fw)
 
@@ -533,6 +565,8 @@ def run(*, conf: Dict[str, Any], mix: Dict[str, Any], seed: int,
             raise RuntimeError(f"load or fleet failed: {gen.error!r} "
                                f"{load.error!r} {fleet.failures!r}")
     finally:
+        if pauses in gc.callbacks:
+            gc.callbacks.remove(pauses)
         if load is not None:
             load.stop()
         fw.stop()
@@ -588,8 +622,11 @@ def run(*, conf: Dict[str, Any], mix: Dict[str, Any], seed: int,
               Check("wrong_length_outputs", short, 0),
               Check("units_never_ready", not_ready, 0),
               Check("units_left_after_delete", leftover, 0)]
-    late = [q["sent"] - q["due"] for q in requests]
-    log(f"generator: at most {max(late, default=0.0) * 1e3:.3f} ms late")
+    late = max(requests, key=lambda q: q["sent"] - q["due"], default=None)
+    if late is not None:
+        log(f"generator: at most {(late['sent'] - late['due']) * 1e3:.3f} "
+            f"ms late (due at +{late['due'] - w0:.3f} s)")
+    log(f"gc in the window: {pauses.summary(w0)}")
     run_ = ServeRun(
         cfg=cfg, w0=w0, w1=w1,
         deadline=deadline, setup_s=setup_s, requests=requests,

@@ -1,5 +1,5 @@
 """Engine admission: harness-clock time inside ``admit_many`` calls made
-in the window, over the number of calls. Moves ttft_p90_ms."""
+in the window, over the number of calls. Moves ttft_p50_ms."""
 
 
 def read(run):

@@ -1,7 +1,7 @@
 """Slot scheduler: 90th percentile of the time from a request's due time
 to the moment the engine proxy received it in ``admit_many``, over every
 request due in the window (one never admitted enters at the drain
-deadline). Moves ttft_p90_ms."""
+deadline). Moves ttft_p50_ms."""
 from bench.harness.common import percentile
 
 

@@ -1,7 +1,7 @@
 """Control plane: mean wait of an item in the syncer's fair queue, from
 the UsageMeter's queue_wait_s over queue_items, summed over the
 control-plane tenants across the window (metering is on in the traced
-run only). Moves propagation_p95_ms."""
+run only). Moves propagation_p90_ms."""
 
 
 def read(run):
