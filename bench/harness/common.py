@@ -158,5 +158,13 @@ def emit(*, checks: List[Check], attempted: int, failed: int,
     return correct
 
 
+def reference_conf(conf: Dict[str, Any]) -> str:
+    """The configuration as a plain reference is handed it: every
+    top-level key but the program's own, as sorted JSON, which a cache of
+    the reference's compiled functions can take as its key."""
+    return json.dumps({k: v for k, v in conf.items() if k != "program"},
+                      sort_keys=True)
+
+
 def now() -> float:
     return time.monotonic()

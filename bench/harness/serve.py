@@ -14,6 +14,7 @@ import functools
 import gc
 import heapq
 import importlib.util
+import json
 import threading
 import time
 from dataclasses import dataclass, field
@@ -22,7 +23,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from .common import (BENCH, OUT, Check, CompileClock, device_info, log, now,
-                     percentile, seed32)
+                     percentile, reference_conf, seed32)
 from .traffic import ServeRequest, UnitCreate, serve_schedule, unit_schedule
 from .weights import make_params, program_config
 
@@ -332,11 +333,13 @@ def load_reference(name: str):
 
 
 @functools.lru_cache(maxsize=None)
-def _gap_fns(ref_name: str, conf_items: Tuple[Tuple[str, Any], ...]):
+def _gap_fns(conf_json: str):
+    """The reference's jitted gap functions for one configuration, handed
+    as :func:`reference_conf` gives it."""
     import jax
     import jax.numpy as jnp
-    ref = load_reference(ref_name)
-    conf = dict(conf_items)
+    conf = json.loads(conf_json)
+    ref = load_reference(conf["reference"])
 
     @jax.jit
     def served_gap(params, tokens, read, served):
@@ -355,12 +358,6 @@ def _gap_fns(ref_name: str, conf_items: Tuple[Tuple[str, Any], ...]):
     return served_gap, control_gap
 
 
-def _conf_key(conf: Dict[str, Any]) -> Tuple[Tuple[str, Any], ...]:
-    keys = ("hidden_size", "num_attention_heads", "num_key_value_heads",
-            "rms_norm_eps", "rope_theta", "num_hidden_layers", "vocab_size")
-    return tuple((k, conf[k]) for k in keys)
-
-
 def logit_gaps(params, conf, mix, samples, *, control: bool = False
                ) -> List[float]:
     """For each sampled (prompt, served tokens): the gap by which each
@@ -369,7 +366,7 @@ def logit_gaps(params, conf, mix, samples, *, control: bool = False
     instead of the served one)."""
     import jax
     import jax.numpy as jnp
-    served_gap, control_gap = _gap_fns(conf["reference"], _conf_key(conf))
+    served_gap, control_gap = _gap_fns(reference_conf(conf))
     S = int(mix["prompt_len"]["max"]) + int(mix["output_len"]["max"])
     R = int(mix["output_len"]["max"])
     out: List[float] = []
@@ -474,7 +471,7 @@ def run(*, conf: Dict[str, Any], mix: Dict[str, Any], seed: int,
     sv = conf["serve"]
     slots, max_len, replicas = sv["slots"], sv["max_len"], sv["replicas"]
     t = now()
-    params = make_params(cfg, seed)
+    params = make_params(conf, seed)
     jax.block_until_ready(params)
     log(f"setup: weights made on device in {now() - t:.3f} s")
 

@@ -18,7 +18,6 @@ import dataclasses
 import functools
 import gc
 import importlib.util
-import json
 import math
 import queue
 import threading
@@ -27,7 +26,8 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from .common import BENCH, OUT, Check, CompileClock, device_info, log, now
+from .common import (BENCH, OUT, Check, CompileClock, device_info, log, now,
+                     reference_conf)
 from .traffic import train_batch
 from .weights import make_params, program_config
 
@@ -228,8 +228,8 @@ def first_steps(prog: Program, conf: Dict[str, Any], seed: int,
     call and feed; the readings of them."""
     import jax
     from repro.training import make_opt_state
-    cfg, sh = prog.cfg, prog.shardings
-    prog.params = make_params(cfg, seed, dtype=np.float32,
+    sh = prog.shardings
+    prog.params = make_params(conf, seed, dtype=np.float32,
                               shardings=sh["params"])
     prog.opt = jax.jit(make_opt_state, out_shardings=sh["opt"])(prog.params)
     b1 = float(conf["optimizer"]["b1"])
@@ -244,7 +244,7 @@ def first_steps(prog: Program, conf: Dict[str, Any], seed: int,
         losses.append(float(met["loss"]))
         if i == 0:       # m = (1 - b1) * g after one step
             grad = _host(norms(prog.opt["m"], 1.0 / (1.0 - b1)))
-    p0 = make_params(cfg, seed, dtype=np.float32, shardings=sh["params"])
+    p0 = make_params(conf, seed, dtype=np.float32, shardings=sh["params"])
     change = _host(change_of(prog.params, p0))
     del p0
     return Readings(losses, grad, change)
@@ -260,17 +260,16 @@ def reference_readings(conf: Dict[str, Any], mix: Dict[str, Any], seed: int,
     ref = load_reference(conf["reference"])
     cfg = program_config(conf)
     mesh = Mesh(np.asarray(devices), (ref.AXIS,))
-    shapes = jax.eval_shape(lambda: make_params(cfg, seed, np.float32))
+    shapes = jax.eval_shape(lambda: make_params(conf, seed, np.float32))
     psh = ref.shardings(mesh, shapes)
     bsh = NamedSharding(mesh, jax.sharding.PartitionSpec(ref.AXIS))
-    rconf = {k: v for k, v in conf.items() if k != "program"}
-    step = ref.make_step(json.dumps(rconf, sort_keys=True), mesh, low)
+    step = ref.make_step(reference_conf(conf), mesh, low)
     zeros = jax.jit(lambda t: jax.tree.map(jnp.zeros_like, t),
                     out_shardings=psh)
     losses: List[float] = []
     grad: Dict[str, np.ndarray] = {}
     with jax.default_matmul_precision("highest"):
-        p = make_params(cfg, seed, dtype=np.float32, shardings=psh)
+        p = make_params(conf, seed, dtype=np.float32, shardings=psh)
         m, v = zeros(p), zeros(p)
         for i in range(n):
             b = batch_of(mix, cfg, seed, i)
@@ -280,7 +279,7 @@ def reference_readings(conf: Dict[str, Any], mix: Dict[str, Any], seed: int,
             if i == 0:
                 grad = _host(g)
         del m, v
-        p0 = make_params(cfg, seed, dtype=np.float32, shardings=psh)
+        p0 = make_params(conf, seed, dtype=np.float32, shardings=psh)
         change = _host(_norm_fns(conf["reference"])[1](p, p0))
     del p, p0
     return Readings(losses, grad, change)

@@ -52,16 +52,16 @@ def _mem(compiled) -> str:
 
 def rehearse_train(cell, conf, mix, topo) -> None:
     import collections
-    import json
     import re
     from bench.harness import train
+    from bench.harness.common import reference_conf
     from bench.harness.weights import make_params
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     devices = topo.devices[:cell["chips"]]
     prog = train.build(conf, mix, devices)
     cfg, sh = prog.cfg, prog.shardings
-    shapes = jax.eval_shape(lambda: make_params(cfg, 0, np.float32))
+    shapes = jax.eval_shape(lambda: make_params(conf, 0, np.float32))
     put = lambda t, s: jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=s)  # noqa
     params = jax.tree.map(put, shapes, sh["params"])
     from repro.training import make_opt_state
@@ -78,7 +78,7 @@ def rehearse_train(cell, conf, mix, topo) -> None:
     print(f"train step: compiled in {time.monotonic() - t:.1f} s; {_mem(c)}; "
           f"collectives {dict(ops)}", flush=True)
     t = time.monotonic()
-    c = jax.jit(lambda: make_params(cfg, 0, np.float32,
+    c = jax.jit(lambda: make_params(conf, 0, np.float32,
                                     shardings=sh["params"])).lower().compile()
     print(f"weights: {time.monotonic() - t:.1f} s; {_mem(c)}", flush=True)
 
@@ -89,13 +89,11 @@ def rehearse_train(cell, conf, mix, topo) -> None:
     bsh = NamedSharding(mesh, P(ref.AXIS))
     rb = jax.tree.map(lambda t: put(t, bsh), jax.eval_shape(
         lambda: train.batch_of(mix, cfg, 0, 0)))
-    rconf = json.dumps({k: v for k, v in conf.items() if k != "program"},
-                       sort_keys=True)
     step_no = jax.ShapeDtypeStruct((), jnp.int32)
     with jax.default_matmul_precision("highest"):
         for low in (False, True):
             t = time.monotonic()
-            c = ref.make_step(rconf, mesh, low).lower(
+            c = ref.make_step(reference_conf(conf), mesh, low).lower(
                 rp, rp, rp, step_no, rb).compile()
             print(f"reference{' float8 control' if low else ''}: "
                   f"{time.monotonic() - t:.1f} s; {_mem(c)}", flush=True)
@@ -107,7 +105,8 @@ def main() -> int:
     ap.add_argument("--ks", default="", help="rows to compile (default all)")
     args = ap.parse_args()
     from bench.run import find_cell, load_benchmark
-    from bench.harness.serve import _conf_key, _gap_fns
+    from bench.harness.common import reference_conf
+    from bench.harness.serve import _gap_fns
     from bench.harness.traffic import serve_schedule
     from bench.harness.weights import program_config
     from jax.experimental import topologies
@@ -164,7 +163,7 @@ def main() -> int:
             print(f"admit k={k} bucket={b}: {time.monotonic() - t:.1f} s; "
                   f"{_mem(c)}", flush=True)
 
-    served_gap, control_gap = _gap_fns(conf["reference"], _conf_key(conf))
+    served_gap, control_gap = _gap_fns(reference_conf(conf))
     S = int(mix["prompt_len"]["max"]) + int(mix["output_len"]["max"])
     R = int(mix["output_len"]["max"])
     with jax.default_matmul_precision("highest"):

@@ -2,9 +2,10 @@
 its configuration file, its traffic mix, the harness module the mix
 names, a function of that module for each end-to-end metric the cell
 reports, and a reader in ``bench/metrics/`` for each per-layer metric
-that names the cell. A serving cell's mix, at its committed rate and the
-benchmark's window, brings at least ten requests beyond the TTFT
-percentile the cell reports. No chip is needed.
+that names the cell. Every leaf of each configuration's program, at its
+committed widths, has a weight rule. A serving cell's mix, at its
+committed rate and the benchmark's window, brings at least ten requests
+beyond the TTFT percentile the cell reports. No chip is needed.
 
     JAX_PLATFORMS=cpu python -m pytest -q bench/tests/test_bench_cells.py
 """
@@ -21,6 +22,8 @@ sys.path.insert(0, str(ROOT / "src"))
 sys.path.insert(0, str(ROOT))
 
 from bench.harness import traffic  # noqa: E402
+from bench.harness.common import load_json  # noqa: E402
+from bench.harness.weights import leaf_rules, program_config  # noqa: E402
 from bench.run import (applies, find_cell, load_benchmark,  # noqa: E402
                        load_metric)
 
@@ -60,6 +63,22 @@ def test_every_per_layer_metric_of_the_cell_has_a_reader(cell):
     assert names
     for name in names:
         assert callable(load_metric(name).read), name
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in BM["configs"]])
+def test_every_leaf_of_the_configuration_has_a_weight_rule(config):
+    """The program's tree at its committed widths, by ``jax.eval_shape``
+    (nothing is allocated): a leaf with no rule fails here, before a chip
+    is booked."""
+    import jax
+    from repro.models import init_params
+    conf = load_json(ROOT / {c["name"]: c for c in BM["configs"]}
+                     [config]["file"])
+    cfg = program_config(conf)
+    shapes = jax.eval_shape(lambda k: init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    rules = leaf_rules(conf, shapes)
+    assert len(rules) == len(jax.tree.leaves(shapes))
 
 
 SERVE_CELLS = [c for c in CELLS if harness_name(c) == "serve"]

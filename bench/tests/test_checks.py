@@ -7,6 +7,11 @@ a decode step that returns its state unchanged, half of an admitted batch
 left out, a token altered where it is produced, and deletes the control
 plane loses. (One chip: there is no exchange between chips to leave out.)
 
+A configuration of sparse experts (``bench/tests/data/tiny-moe.json``)
+enters as files alone: its weights from its own rules, its reference
+handed every key of its configuration but the program's, and a whole
+serving run of it finishes with every check read.
+
     JAX_PLATFORMS=cpu python -m pytest -q bench/tests/test_checks.py
 """
 import json
@@ -157,6 +162,51 @@ def test_control_plane_loses_deletes(monkeypatch):
     assert not correct(r)
     assert next(c for c in r.checks
                 if c.name == "units_left_after_delete").value > 0
+
+
+def moe_conf():
+    return json.loads((ROOT / "bench/tests/data/tiny-moe.json").read_text())
+
+
+def test_reference_is_handed_the_whole_configuration(monkeypatch):
+    """What ``logits`` receives: every top-level key of the configuration
+    file except ``program``, the experts per token among them."""
+    from bench.harness.weights import make_params
+    conf, (_, mix) = moe_conf(), tiny()
+    seen = []
+
+    class Stub:
+        @staticmethod
+        def logits(params, conf, tokens, read, low=False):
+            seen.append(conf)
+            return jnp.zeros((read.shape[0], conf["vocab_size"]))
+
+    monkeypatch.setattr(serve, "load_reference", lambda name: Stub)
+    serve._gap_fns.cache_clear()
+    try:
+        gaps = serve.logit_gaps(make_params(conf, 5), conf, mix,
+                                [([1, 2, 3], [4, 5])])
+    finally:
+        serve._gap_fns.cache_clear()
+    assert gaps == [0.0, 0.0]
+    assert seen and all(c == {k: v for k, v in conf.items()
+                              if k != "program"} for c in seen)
+    assert seen[0]["num_experts_per_tok"] == 2
+
+
+def test_sparse_expert_configuration_serves_a_whole_run():
+    """tiny-moe through the whole serving path with its own rules and its
+    own reference. Every check is read and finite; ``correct`` is not
+    asserted, since the program's capacity dispatch may drop a token that
+    its experts overflow."""
+    _, mix = tiny()
+    r = serve.run(conf=moe_conf(), mix=mix, seed=2**31 + 12,
+                  seconds=SECONDS, trace=False, t_process=time.monotonic(),
+                  devices=jax.devices())
+    assert {c.name for c in r.checks} >= {"logit_gap", "unfinished_requests"}
+    assert all(math.isfinite(c.value) for c in r.checks), \
+        [(c.name, c.value) for c in r.checks]
+    assert r.extra["sampled_tokens"] > 20
 
 
 if __name__ == "__main__":
