@@ -156,6 +156,30 @@ def cast_params(params: Dict[str, Any]) -> Dict[str, Any]:
     return out
 
 
+# leaves that the model reads only through ``.astype(compute_dtype)``, by
+# (parent key, key); besides these, every dense ``w`` and ``b`` but those
+# of mamba's ``dt_proj``, which ``mamba_apply`` reads in float32
+_COMPUTE_READ = {("embed", "table"), ("ffn", "w1"), ("ffn", "wg"),
+                 ("ffn", "w2")}
+
+
+def compute_read(params: Dict[str, Any]) -> Dict[str, Any]:
+    """A tree beside ``params`` that is True at each leaf the model reads
+    only through ``.astype(compute_dtype)``: the dense, GLU, attention,
+    MoE-expert and head weights and biases, and the embedding table. A
+    copy of such a leaf cast ahead once gives the very values every use
+    would cast. False at leaves read in float32: norm scales, the MoE
+    router, mamba's ``dt_proj``, conv and SSM leaves, rwkv's mixing and
+    decay weights."""
+    def read(path, _):
+        keys = tuple(k.key for k in path)
+        parent, name = (("",) + keys)[-2:]
+        if name in ("w", "b"):
+            return parent != "dt_proj"
+        return (parent, name) in _COMPUTE_READ
+    return jax.tree_util.tree_map_with_path(read, params)
+
+
 def param_axes(cfg: ModelConfig) -> Dict[str, Any]:
     axes: Dict[str, Any] = {
         "embed": embed_axes(),

@@ -20,6 +20,22 @@ across every engine replica of the same model:
   slot, computing done-flags device-side, and returning ``(tokens, done)``
   so the host syncs ONCE per step instead of once per slot.
 
+The engine serves from one compute-dtype copy of the weights. Every leaf
+the model reads only through ``.astype(compute_dtype)``
+(:func:`repro.models.compute_read`: the dense, GLU, attention, MoE-expert
+and head weights and biases, and the embedding table) is cast once, in one
+jitted program, and handed to the programs in place of the held leaf, so
+each cast inside them is a no-op and a step reads the weights once in the
+compute dtype (for bf16 over f32-held weights: a third of the bytes it
+read, wrote and read back before). Leaves read in float32 (norm scales,
+the MoE router, mamba's ``dt_proj``, rwkv's decay weights) and leaves
+already in the compute dtype are passed through as they are. The copy is
+shared: a module-level table, keyed on the source leaves and holding both
+them and the copy weakly, hands every engine over the same params tree the
+very same cast leaves, made once under a lock (fleet replicas come up from
+their own threads, and a copy each would not fit beside the held weights).
+Engines hold the copy; it is freed with the last of them.
+
 Slot state lives on device between calls (lengths, token budgets, active
 mask, last token per slot); the host keeps only the request objects and a
 free-slot map. ``ContinuousBatcher`` fronts one engine with a thread-safe
@@ -32,14 +48,15 @@ from __future__ import annotations
 import functools
 import threading
 import time
+import weakref
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import decode_step, init_cache, prefill
+from ..models import compute_read, decode_step, init_cache, prefill
 from ..models.config import ModelConfig
 
 from .scheduler import SlotScheduler
@@ -124,6 +141,55 @@ def _compiled(cfg: ModelConfig, max_len: int, compute_dtype):
             jax.jit(step, donate_argnums=(1, 2, 3, 4, 5)))
 
 
+# ------------------------------------------------- compute-dtype weights
+
+# (dtype, ids of the source leaves) -> (weakrefs to the source leaves,
+# weakrefs to their cast copies); the table holds nothing alive
+_copies: Dict[tuple, Tuple[list, list]] = {}
+_copies_lock = threading.Lock()
+
+
+@functools.lru_cache(maxsize=None)
+def _caster(dtype):
+    return jax.jit(lambda leaves: [x.astype(dtype) for x in leaves])
+
+
+def _live(entry: Tuple[list, list]) -> Optional[list]:
+    """The entry's cast leaves, while they and their sources all live (a
+    live source is the leaf its id names in the key)."""
+    got = [r() for r in entry[0] + entry[1]]
+    return None if any(x is None for x in got) else got[len(entry[0]):]
+
+
+def _serving_params(params: Any, compute_dtype) -> Tuple[Any, int]:
+    """``params`` with each leaf the model reads only through
+    ``.astype(compute_dtype)`` in ``compute_dtype``, and the number of
+    copies made for it (0 or 1). Callers over the same source leaves share
+    one copy while any of them holds it."""
+    dtype = jnp.dtype(compute_dtype)
+    leaves, treedef = jax.tree.flatten(params)
+    reads = jax.tree.leaves(compute_read(params))
+    todo = [i for i, (x, r) in enumerate(zip(leaves, reads))
+            if r and x.dtype != dtype]
+    if not todo:
+        return params, 0
+    src = [leaves[i] for i in todo]
+    key = (dtype.name,) + tuple(map(id, src))
+    with _copies_lock:
+        cast = {k: _live(e) for k, e in _copies.items()}
+        for k in [k for k, c in cast.items() if c is None]:
+            del _copies[k]
+        cast = cast.get(key)
+        made = int(cast is None)
+        if made:
+            cast = _caster(dtype)(src)
+            _copies[key] = ([weakref.ref(x) for x in src],
+                            [weakref.ref(x) for x in cast])
+    for i, x in zip(todo, cast):
+        leaves[i] = x
+    return jax.tree.unflatten(treedef, leaves), made
+
+
 class GenerationEngine:
     """Slot-based engine: fused bucketed admission, donated joint decode.
 
@@ -135,7 +201,10 @@ class GenerationEngine:
     def __init__(self, cfg: ModelConfig, params: Any, *, slots: int = 4,
                  max_len: int = 512, compute_dtype=jnp.bfloat16):
         self.cfg = cfg
-        self.params = params
+        # what the programs read, and the compute-dtype copies this engine
+        # made for it (0 when it shares another engine's or needs none)
+        self.params, self.weight_casts = _serving_params(params,
+                                                        compute_dtype)
         self.slots = slots
         self.max_len = max_len
         self.compute_dtype = compute_dtype
@@ -270,7 +339,8 @@ class GenerationEngine:
         return {"steps": self.steps, "admit_calls": self.admit_calls,
                 "admitted": self.admitted,
                 "full_cache_copies": self.full_cache_copies,
-                "host_syncs": self.host_syncs}
+                "host_syncs": self.host_syncs,
+                "weight_casts": self.weight_casts}
 
 
 class ContinuousBatcher:

@@ -1,12 +1,18 @@
 """Serving data plane: fused-admission engine exactness vs the models-API
 reference loop (ragged attention batches + recurrent exact-length buckets),
-admission under full slots with slot reuse, thread-safe batcher submits with
-TTFT stamps, WRR slot-scheduler fairness vs the FIFO baseline, greedy-flood
-starvation regression, the control→data plane bridge (engine replicas as
-WorkUnits, per-tenant metrics), agent cleanup of deleted units, and the
-autoscaler's fourth (engine-replica) actuator."""
+the shared compute-dtype weight copy (bit-exact against the per-use cast
+for every layer pattern, made once across engines and threads, freed with
+the last engine, skipped for bf16 params), admission under full slots with
+slot reuse, thread-safe batcher submits with TTFT stamps, WRR
+slot-scheduler fairness vs the FIFO baseline, greedy-flood starvation
+regression, the control→data plane bridge (engine replicas as WorkUnits,
+per-tenant metrics), agent cleanup of deleted units, and the autoscaler's
+fourth (engine-replica) actuator."""
+import gc
+import sys
 import threading
 import time
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -18,11 +24,13 @@ from repro.core import (APIServer, Autoscaler, CooperativeExecutor,
                         ScalingPolicy, Syncer, TenantControlPlane,
                         VirtualClusterFramework)
 from repro.core.agent import MockProvider
-from repro.models import decode_step, init_cache, init_params, prefill
+from repro.models import (cast_params, compute_read, decode_step, init_cache,
+                          init_params, prefill)
 from repro.serving import (ContinuousBatcher, GenerationEngine, Request,
                            ServingFleet, SlotScheduler, generate)
 
 F32 = jnp.float32
+BF16 = jnp.bfloat16
 MAX_LEN = 48
 
 
@@ -165,6 +173,171 @@ def test_generate_routes_through_engine(model):
     with pytest.raises(ValueError):
         generate(cfg, params, batch, max_new_tokens=MAX_LEN,
                  max_len=MAX_LEN, compute_dtype=F32)
+
+
+# ------------------------------------------------- compute-dtype weights
+
+# one config per layer pattern the engine serves: dense 'g'; gemma2 'lg'
+# (soft-caps, tied head, scaled embedding); MoE blocks; jamba 'm' with MoE;
+# rwkv 'r'; the encoder-decoder
+COPY_ARCHS = ["qwen2-7b", "gemma2-9b", "olmoe-1b-7b", "jamba-v0.1-52b",
+              "rwkv6-7b", "seamless-m4t-large-v2"]
+
+
+def _f32_model(name, seed=0):
+    cfg = get_config(name)
+    cfg = reduced(cfg, n_layers=cfg.block_period)
+    return cfg, init_params(jax.random.PRNGKey(seed), cfg)
+
+
+def _paths(tree):
+    return {jax.tree_util.keystr(p): x
+            for p, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def _greedy_bf16(cfg, params, prompts, max_new):
+    """The models API, jitted, with bf16 compute over ``params`` as given
+    (so float32 leaves are cast at every use): the first prefill and
+    decode logits, and every greedy token, at the engine's shapes."""
+    pf = jax.jit(lambda p, t, c: prefill(p, cfg, t, c, compute_dtype=BF16))
+    ds = jax.jit(lambda p, t, c, n: decode_step(p, cfg, t, c, n,
+                                                compute_dtype=BF16))
+    B = prompts.shape[0]
+    cache = init_cache(cfg, B, MAX_LEN, enc_len=MAX_LEN)
+    logits, cache, lengths = pf(params, jnp.asarray(prompts), cache)
+    first = [logits]
+    toks = [jnp.argmax(logits[:, -1, :cfg.vocab], axis=-1)]
+    lengths = lengths + 1
+    for _ in range(max_new - 1):
+        logits, cache, lengths = ds(params, toks[-1][:, None].astype(
+            jnp.int32), cache, lengths)
+        first.append(logits)
+        toks.append(jnp.argmax(logits[:, 0, :cfg.vocab], axis=-1))
+    return first[:2], np.stack([np.asarray(t) for t in toks], axis=1)
+
+
+@pytest.mark.parametrize("name", COPY_ARCHS)
+def test_compute_copy_serves_f32_params_exactly(name):
+    """Over float32-held params the engine serves from its bf16 copy: the
+    prefill and first decode logits equal, bit for bit, those of the model
+    casting each float32 leaf where it reads it, and every token matches."""
+    cfg, params = _f32_model(name)
+    prompts = np.random.default_rng(7).integers(
+        0, cfg.vocab, (3, 8)).astype(np.int32)
+    eng = GenerationEngine(cfg, params, slots=3, max_len=MAX_LEN,
+                           compute_dtype=BF16)
+    assert eng.counters()["weight_casts"] == 1
+    ref_logits, ref_toks = _greedy_bf16(cfg, params, prompts, 5)
+    copy_logits, copy_toks = _greedy_bf16(cfg, eng.params, prompts, 5)
+    for a, b in zip(ref_logits, copy_logits):
+        assert jnp.array_equal(a, b)
+    reqs = [Request(i, p, max_new_tokens=5) for i, p in enumerate(prompts)]
+    eng.admit_many(reqs)      # equal lengths: one bucket, slots 0..2
+    while eng.active_slots():
+        eng.step()
+    assert np.array_equal(copy_toks, ref_toks)
+    assert [r.tokens for r in reqs] == ref_toks.tolist()
+
+
+def test_f32_read_leaves_keep_dtype():
+    """Leaves the model reads in float32 are served as held (the very
+    arrays); leaves read through the compute-dtype cast are bf16."""
+    for name in ("jamba-v0.1-52b", "rwkv6-7b"):
+        cfg, params = _f32_model(name)
+        eng = GenerationEngine(cfg, params, slots=1, max_len=MAX_LEN,
+                               compute_dtype=BF16)
+        src, served = _paths(params), _paths(eng.params)
+        reads = _paths(compute_read(params))
+        for path, x in served.items():
+            if reads[path]:
+                assert x.dtype == BF16, path
+            else:
+                assert x is src[path], path
+        assert not any(reads[p] for p in src
+                       if p.endswith(("['ln1']", "['ln2']", "['final_norm']",
+                                      "['router']", "['dt_proj']['w']",
+                                      "['dt_proj']['b']", "['decay_w1']",
+                                      "['decay_w2']")))
+    jamba = _paths(compute_read(_f32_model("jamba-v0.1-52b")[1]))
+    for leaf in ("['embed']['table']", "['blocks']['sub0']['mamba']"
+                 "['x_proj']['w']", "['blocks']['sub1']['ffn']['w1']",
+                 "['blocks']['sub4']['attn']['wq']['w']"):
+        assert jamba[leaf], leaf
+
+
+def test_engines_share_one_compute_copy(model):
+    cfg, params = model
+    a = GenerationEngine(cfg, params, slots=1, max_len=MAX_LEN,
+                         compute_dtype=BF16)
+    b = GenerationEngine(cfg, params, slots=2, max_len=MAX_LEN,
+                         compute_dtype=BF16)
+    assert a.weight_casts + b.weight_casts == 1
+    pa, pb = jax.tree.leaves(a.params), jax.tree.leaves(b.params)
+    assert all(x is y for x, y in zip(pa, pb))
+    assert sum(x.dtype == BF16 for x in pa) > 0
+
+
+def test_compute_copy_made_once_across_threads():
+    """Fleet replicas come up from their own threads: engines built at
+    once still make one copy between them."""
+    cfg, params = _f32_model("qwen2-7b", seed=3)
+    engines, errors = [], []
+    gate = threading.Barrier(4)
+
+    def build():
+        try:
+            gate.wait(timeout=30)
+            engines.append(GenerationEngine(cfg, params, slots=1,
+                                            max_len=MAX_LEN,
+                                            compute_dtype=BF16))
+        except Exception as e:       # reported below, not lost in a thread
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=build) for _ in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors and len(engines) == 4
+    assert sum(e.weight_casts for e in engines) == 1
+    first = jax.tree.leaves(engines[0].params)
+    for e in engines[1:]:
+        assert all(x is y for x, y in zip(first, jax.tree.leaves(e.params)))
+
+
+def test_compute_copy_freed_with_last_engine():
+    cfg, params = _f32_model("qwen2-7b", seed=4)
+    a = GenerationEngine(cfg, params, slots=1, max_len=MAX_LEN,
+                         compute_dtype=BF16)
+    b = GenerationEngine(cfg, params, slots=1, max_len=MAX_LEN,
+                         compute_dtype=BF16)
+    table = weakref.ref(a.params["embed"]["table"])
+    assert table().dtype == BF16
+    del a
+    gc.collect()
+    assert table() is not None           # b still serves from it
+    del b
+    gc.collect()
+    assert table() is None
+    c = GenerationEngine(cfg, params, slots=1, max_len=MAX_LEN,
+                         compute_dtype=BF16)
+    assert c.weight_casts == 1           # made anew, not found stale
+
+
+def test_bf16_params_need_no_copy(model):
+    cfg, params = model
+    held = cast_params(params)
+    eng = GenerationEngine(cfg, held, slots=1, max_len=MAX_LEN,
+                           compute_dtype=BF16)
+    assert eng.counters()["weight_casts"] == 0
+    assert all(x is y for x, y in zip(jax.tree.leaves(eng.params),
+                                      jax.tree.leaves(held)))
 
 
 # ------------------------------------------------- admission under full slots
