@@ -75,15 +75,15 @@ def _run_one(fair: bool, greedy: int, greedy_units: int, regular: int,
             return outs
 
         # tenant -> owning downward shard (consistent-hash placement)
-        shard_of = {name: reg.shard.shard_id
-                    for name, reg in fw.syncer.tenants.items()}
+        shard_of = {name: shard.shard_id
+                    for name, shard in fw.syncer.down.owner.items()}
         # per-tenant DOWNWARD QUEUE WAIT: the layer the paper's fairness
         # mechanism operates on (WRR dispatch delay), and the right place to
         # read cross-shard isolation — end-to-end Ready latency also folds
         # in the shared sequential scheduler, which dominates at this
         # reproduction's syncer throughput and affects every tenant alike
         queue_wait: Dict[str, float] = {}
-        for c in fw.syncer.shard_controllers:
+        for c in fw.syncer.down.shards:
             for tenant, waits in c.queue.per_tenant_wait.items():
                 if waits:
                     queue_wait[tenant] = statistics.mean(waits)

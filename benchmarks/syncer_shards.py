@@ -30,7 +30,7 @@ Two executor-only scenarios cover the UPWARD axis:
   flap status rapidly while a recorder emits deduplicated Events per flap;
   the clock stops when every tenant plane shows the final phase AND the
   final event counts. Run once on the per-item FIFO baseline
-  (``upward_shards=1, batch_upward=False``) and swept across coalesced
+  (``upward_shards=1, upward_batch=1``) and swept across coalesced
   shard counts; ``--smoke`` gates coalesced >= 1.2x per-item.
 - ``tracing_overhead`` — the churn workload run twice per repeat: tracer
   wired end to end at the production posture sample=0.1 (traceparent
@@ -207,7 +207,7 @@ def _rig(shards: int, batch: int, tenants: int, downward_workers: int,
 
 def _batch_totals(syncer: Syncer):
     """(sum, count) of realized dequeue batch sizes across all shards."""
-    snap = syncer.up_controller.metrics.snapshot()
+    snap = syncer.controllers[0].metrics.snapshot()
     down = [s for k, s in snap["summaries"].items()
             if k.startswith("batch_size{controller=syncer-dws")]
     return sum(s["sum"] for s in down), sum(s["count"] for s in down)
@@ -219,7 +219,7 @@ def _reset_phase_stats(syncer: Syncer):
     subtract, so reported stats describe only the timed phase. Also clears
     collection debt and freezes the GC so a cycle pause can't land
     mid-phase (re-enabled in each scenario's ``finally``)."""
-    for c in syncer.shard_controllers:
+    for c in syncer.down.shards:
         c.queue.per_tenant_wait.clear()
     gc.collect()
     gc.disable()
@@ -229,7 +229,7 @@ def _reset_phase_stats(syncer: Syncer):
 def _collect(syncer: Syncer, super_api: APIServer, rec: Dict,
              batch_base=(0.0, 0.0)) -> Dict:
     waits: List[float] = []
-    for c in syncer.shard_controllers:
+    for c in syncer.down.shards:
         for per in c.queue.per_tenant_wait.values():
             waits.extend(per)
     bsum, bcount = _batch_totals(syncer)
@@ -697,7 +697,7 @@ def _run_status_storm(upward_shards, batch_upward, tenants, per_tenant,
     serialized) super-store writes and measures the submitter, not the
     syncer.
 
-    ``upward_shards=1, batch_upward=False`` with unfair queuing is the
+    ``upward_shards=1, upward_batch=1`` with unfair queuing is the
     per-item FIFO baseline (the seed's shared upward queue); coalesced
     configs run sharded WRR with batched ``update_status_batch`` writes.
     The TOTAL upward worker budget is held constant across configs (the
@@ -711,12 +711,15 @@ def _run_status_storm(upward_shards, batch_upward, tenants, per_tenant,
     """
     super_api = APIServer("super")
     executor = CooperativeExecutor(8 + upward_workers, name="bench-storm")
+    # the per-item baseline syncs one key at a time; the coalesced arm keeps
+    # the default upward batch
+    batching = {} if batch_upward else {"upward_batch": 1}
     syncer = Syncer(super_api, downward_workers=8,
                     upward_workers=upward_workers,
                     fair_queuing=batch_upward,   # baseline = true shared FIFO
                     scan_interval=0.0, shards=1, downward_batch=4,
-                    upward_shards=upward_shards, batch_upward=batch_upward,
-                    executor=executor)
+                    upward_shards=upward_shards, executor=executor,
+                    **batching)
     planes = [TenantControlPlane(f"t{i:03d}") for i in range(tenants)]
     for i, p in enumerate(planes):
         syncer.register_tenant(p, f"uid-{i:03d}")
@@ -775,7 +778,7 @@ def _run_status_storm(upward_shards, batch_upward, tenants, per_tenant,
         _wait(lambda: all(converged(p) for p in planes))
         elapsed = time.monotonic() - t0
         ops = tenants * per_tenant * flaps * 2
-        coalesced = syncer.upward.coalesced_total()
+        coalesced = syncer.up.coalesced_total()
         return {
             "scenario": "status_storm", "mode": "executor",
             "upward_shards": upward_shards, "batch_upward": batch_upward,
@@ -868,7 +871,7 @@ def _run_autoscale(tenants: int, per_tenant: int, waves: int = 3,
     executor = CooperativeExecutor(2, name="bench-as")
     syncer = Syncer(super_api, downward_workers=8, upward_workers=4,
                     scan_interval=0.0, shards=1, downward_batch=4,
-                    upward_shards=1, batch_upward=True, executor=executor)
+                    upward_shards=1, executor=executor)
     policy = ScalingPolicy(min_shards=1, max_shards=8, shard_up_depth=16.0,
                            shard_down_depth=1.0,
                            min_upward_shards=1, max_upward_shards=8,

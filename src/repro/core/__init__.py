@@ -11,6 +11,7 @@ from .metering import DETECTOR_AXES, UsageMeter, obj_nbytes
 from .objects import (KINDS, ConfigMap, Event, Namespace, Node, Secret,
                       Service, VirtualClusterCR, VirtualNode, WorkUnit,
                       WorkUnitSpec)
+from .pipeline import ShardedPipeline, SyncShard
 from .ring import ShardRing, shard_for
 from .router import IsolationViolation, MeshRouter
 from .runtime import (Controller, ControllerManager, Histogram,
@@ -23,7 +24,7 @@ from .store import (ADDED, BOOKMARK, DELETED, MODIFIED, AlreadyExistsError,
 from .syncer import Syncer, ns_prefix
 from .tenant_operator import TenantOperator
 from .trace import TRACEPARENT_KEY, Span, Tracer
-from .upward import EventRecorder, UpwardPipeline, UpwardShard
+from .upward import EventRecorder, UpwardSync
 from .vnode import VNodeManager
 from .workqueue import DelayingQueue, RateLimiter, WorkQueue
 
@@ -37,7 +38,7 @@ __all__ = [
     "FairWorkQueue", "WorkQueue", "DelayingQueue", "RateLimiter",
     "Informer", "InformerCache", "ObjectStore", "Syncer", "ns_prefix",
     "shard_for", "ShardRing",
-    "EventRecorder", "UpwardPipeline", "UpwardShard",
+    "ShardedPipeline", "SyncShard", "EventRecorder", "UpwardSync",
     "SuperScheduler", "TenantOperator", "VNodeManager", "MeshRouter",
     "IsolationViolation", "NodeAgent", "VnAgent", "Provider", "MockProvider",
     "CallableProvider", "WorkUnit", "WorkUnitSpec", "Service", "Secret",

@@ -301,7 +301,7 @@ class Autoscaler(Controller):
         # signals are read from, and the tick schedules on the pool it
         # scales. A ControllerManager.add() overrides both (same objects in
         # the framework wiring).
-        self.metrics = syncer.up_controller.metrics
+        self.metrics = syncer.controllers[0].metrics
         self.executor = executor
         self.policy = policy or ScalingPolicy()
         p = self.policy
@@ -404,10 +404,9 @@ class Autoscaler(Controller):
         """Windowed mean reconcile latency across ``controllers`` from the
         cumulative summaries: delta(sum)/delta(count) since the last tick.
         Zero when idle, so the latency window decays and permits shrink."""
-        reg = self.syncer.up_controller.metrics
         dsum = dcount = 0.0
         for c in controllers:
-            s = reg.summary("reconcile_seconds", controller=c.name)
+            s = c.metrics.summary("reconcile_seconds", controller=c.name)
             psum, pcount = self._prev_reconcile.get(c.name, (0.0, 0.0))
             dsum += s["sum"] - psum
             dcount += s["count"] - pcount
@@ -418,12 +417,12 @@ class Autoscaler(Controller):
         # hot-shard depth: the max per-shard fair-queue depth is the signal
         # (a single overloaded shard must be able to trigger growth even
         # when the fleet average looks healthy)
-        shards = list(self.syncer.shard_controllers)
+        shards = list(self.syncer.down.shards)
         depth = max((len(c.queue) for c in shards), default=0)
         self.w_depth.observe(depth, now)
         self.w_latency.observe(self._windowed_latency(shards), now)
         # same two signals on the upward axis (its own shard fleet)
-        ushards = list(self.syncer.upward.controllers)
+        ushards = list(self.syncer.up.shards)
         udepth = max((len(c.queue) for c in ushards), default=0)
         self.w_up_depth.observe(udepth, now)
         self.w_up_latency.observe(self._windowed_latency(ushards), now)
@@ -577,8 +576,8 @@ class Autoscaler(Controller):
                      for r in um.noisy(p.noisy_threshold)}
         with self._state_lock:
             self._last_noisy = dict(noisy)
-        queues = ([c.queue for c in sy.shard_controllers]
-                  + [c.queue for c in sy.upward.controllers])
+        queues = ([c.queue for c in sy.down.shards]
+                  + [c.queue for c in sy.up.shards])
         for q in queues:
             if not getattr(q, "fair", False):
                 continue

@@ -43,12 +43,14 @@ class VirtualClusterFramework:
     ``executor_mode=False`` is the legacy blocking-thread fallback
     (one thread per informer/worker/scan loop).
 
-    The upward status/event path mirrors the downward one: tenant-hash
-    upward shards (``upward_shards``, default = ``syncer_shards``) with
-    per-object latest-wins coalescing and batched tenant-plane writes
-    (``batch_upward``, on by default), plus kubelet-style Events recorded by
-    the node agents (``record_events``) and synced into tenant planes with
-    their dedup counts.
+    Both sync directions run on one sharded pipeline design: tenant-hash
+    shards (``syncer_shards`` down, ``upward_shards`` up, defaulting to
+    ``syncer_shards``) that coalesce same-tenant bursts into batches
+    (``downward_batch``; ``upward_batch``, where 1 is the per-item path).
+    Upward, that means latest-wins statuses and batched tenant-plane writes,
+    plus kubelet-style Events recorded by the node agents
+    (``record_events``) and synced into tenant planes with their dedup
+    counts.
 
     ``autoscale=True`` adds the closed-loop :class:`Autoscaler` as a sixth
     controller: it grows/shrinks the downward shard fleet
@@ -71,7 +73,6 @@ class VirtualClusterFramework:
                  syncer_shards: int = 1,
                  downward_batch: int = 1,
                  upward_shards: Optional[int] = None,
-                 batch_upward: bool = True,
                  upward_batch: int = 16,
                  record_events: bool = True,
                  executor_mode: bool = True,
@@ -133,7 +134,6 @@ class VirtualClusterFramework:
                              shards=syncer_shards,
                              downward_batch=downward_batch,
                              upward_shards=upward_shards,
-                             batch_upward=batch_upward,
                              upward_batch=upward_batch,
                              record_events=record_events,
                              executor=self.executor,

@@ -8,25 +8,19 @@ queue. Per-resource reconcilers perform:
 - downward synchronization: tenant spec -> super cluster (namespace-prefixed);
 - upward synchronization: super status -> tenant control plane (vNode-mapped).
 
-Scaling beyond the paper, the downward path is **sharded by tenant UID over
-a consistent-hash ring** (``ring_vnodes`` virtual nodes per shard): ``shards``
-independent :class:`~repro.core.runtime.Controller` workers each own a
-per-shard fair queue serving the tenants that hash onto them. Every tenant
-deterministically lands on one shard (stable across restarts), growing the
-fleet via :meth:`Syncer.resize_shards` live-migrates only ~1/N of the
-tenants, per-shard WRR preserves the Fig.11 fairness guarantees, and
-same-tenant bursts are coalesced into batches (``downward_batch``) covering
-the full CRUD surface — batched creates, spec updates, AND deletes — issued
-through a per-shard super-API client (dedicated token bucket), so shards
-never serialize on one bucket lock.
-
-The upward path mirrors it (see :mod:`repro.core.upward`): tenant-hash
-**upward shards** on their own consistent-hash ring, each with a per-tenant
-fair queue and its own super-API client, per-object latest-wins status
-coalescing, and batched tenant-plane writes (``batch_upward``, on by
-default); :class:`~repro.core.objects.Event` objects recorded in the super
-cluster are synced upward with their dedup counts so tenants can list their
-own events.
+Both directions run on one :class:`~repro.core.pipeline.ShardedPipeline`,
+instantiated twice (``down`` and ``up``): tenants hash by UID onto shards of
+a consistent-hash ring, each shard owning a per-tenant fair queue (WRR, so
+the Fig.11 fairness holds per shard) and its own super-API client, and
+same-tenant bursts coalesce into batches (``downward_batch``,
+``upward_batch``). Growing or shrinking either fleet
+(:meth:`Syncer.resize_shards`, :meth:`Syncer.resize_upward_shards`)
+live-migrates only ~1/N of the tenants. The downward fast path covers the
+full CRUD surface (batched creates, spec updates, and deletes); the upward
+one (:mod:`repro.core.upward`) writes latest-wins statuses in one batch per
+tenant plane, and syncs :class:`~repro.core.objects.Event` objects recorded
+in the super cluster with their dedup counts so tenants can list their own
+events.
 
 State comparisons are made against informer caches, never the apiservers.
 A periodic scan remediates rare permanently-inconsistent states by re-sending
@@ -50,17 +44,17 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
 from .apiserver import APIServer, TenantControlPlane
-from .fairqueue import FairWorkQueue
 from .informer import Informer
 from .metering import obj_nbytes
 from .objects import (SYNCED_KINDS_DOWNWARD, SYNCED_KINDS_UPWARD, Namespace,
                       deepcopy_obj, obj_kind, spec_equal, status_equal)
-from .ring import ShardRing, shard_for  # noqa: F401  (re-export: public API)
+from .pipeline import ShardedPipeline, SyncShard
+from .ring import shard_for  # noqa: F401  (re-export: public API)
 from .runtime import Controller, MetricsRegistry, RetryLater
 from .trace import TRACEPARENT_KEY, sampled_carrier
 from .store import (ADDED, MODIFIED, AlreadyExistsError, ConflictError,
                     NotFoundError)
-from .upward import UpwardPipeline
+from .upward import UpwardSync
 from .vnode import VNodeManager
 
 DownItem = Tuple[str, str, str]        # (kind, tenant_ns, name) under a tenant
@@ -141,111 +135,18 @@ class SyncerMetrics:
 
 
 class TenantRegistration:
-    """Everything the syncer holds per tenant."""
+    """Everything the syncer holds per tenant (its owning shards live in
+    the pipelines' tenant -> shard maps)."""
 
-    def __init__(self, plane: TenantControlPlane, prefix: str,
-                 shard: "_DownwardShard", uid: str = "",
-                 upward_shard: Optional[Any] = None):
+    def __init__(self, plane: TenantControlPlane, prefix: str, uid: str = ""):
         self.plane = plane
         self.prefix = prefix
-        self.shard = shard     # current owning downward shard; swaps on resize
-        self.upward_shard = upward_shard   # current owning upward shard
         self.uid = uid or plane.name
         self.informers: Dict[str, Informer] = {}
         # super namespaces already ensured for this tenant (coalesces the
         # per-item existence probe before super-cluster writes)
         self.ensured_ns: set = set()
         self.ensured_lock = threading.Lock()
-
-
-class _DownwardShard(Controller):
-    """One downward shard: a per-shard fair queue + workers for the tenants
-    hashed onto it. Retries Conflict/AlreadyExists (informer-cache races).
-
-    Each shard talks to the super cluster through its OWN ``APIClient``
-    (dedicated token bucket over the shared store), so batched writes from
-    different shards never serialize on one bucket lock.
-    """
-
-    def __init__(self, syncer: "Syncer", shard_id: int, *, workers: int,
-                 fair: bool, batch_size: int):
-        super().__init__(f"syncer-dws-{shard_id}",
-                         queue=FairWorkQueue(f"downward-{shard_id}", fair=fair),
-                         workers=workers, batch_size=batch_size,
-                         retry_on=(ConflictError, AlreadyExistsError,
-                                   RetryLater),
-                         drop_on=())
-        self.syncer = syncer
-        self.shard_id = shard_id
-        self.api = syncer.super_api.client(f"dws-{shard_id}")
-        # shards created after wiring (resize) inherit the live meter
-        self.queue.meter = syncer._meter
-
-    def _retry_queue(self, item: Any) -> Any:
-        """Retries re-enter the tenant's CURRENT shard: if resize_shards
-        migrated the tenant while this item was in flight, re-adding to our
-        own (drained, possibly about-to-stop) queue would strand the key."""
-        reg = self.syncer.tenants.get(item[0])   # GIL-atomic dict read
-        return reg.shard.queue if reg is not None else self.queue
-
-    def reconcile(self, item: Any) -> None:
-        tenant, (kind, ns, name) = item
-        sy = self.syncer
-        tl = None
-        if kind == "WorkUnit":
-            tl = sy.metrics.timeline(tenant, ns, name)
-            if tl.dws_dequeue == 0.0:
-                tl.dws_dequeue = time.time()
-        sy._reconcile_down(tenant, kind, ns, name, api=self.api)
-        # stamped only on success: a raise above means the item is retried,
-        # and a finally-stamp would make fig7/fig8 undercount retried syncs
-        if tl is not None and tl.dws_done == 0.0:
-            tl.dws_done = time.time()
-
-    def reconcile_batch(self, items: List[Any]) -> None:
-        """Coalesce a same-tenant burst: cache-based state comparison plus
-        batched super-cluster writes over the full CRUD surface (creates,
-        spec updates, deletes); leftovers (Namespace objects, cache races)
-        take the authoritative per-item path."""
-        if len(items) == 1:
-            return self._reconcile_one(items[0])
-        tenant = items[0][0]
-        now = time.time()
-        for _, (kind, ns, name) in items:
-            if kind == "WorkUnit":
-                tl = self.syncer.metrics.timeline(tenant, ns, name)
-                if tl.dws_dequeue == 0.0:
-                    tl.dws_dequeue = now
-        t0 = time.monotonic()
-        try:
-            fast, slow = self.syncer._reconcile_down_fast(
-                tenant, [key for _, key in items], api=self.api)
-        except Exception:
-            # fast path failed as a unit; fall back to per-item reconciles
-            # below, but surface the failure in metrics
-            self.metrics.inc("fast_path_errors", controller=self.name)
-            fast, slow = [], [key for _, key in items]
-        dur = time.monotonic() - t0
-        done = time.time()
-        fast_items = []
-        for key in fast:
-            fast_items.append((tenant, key))
-            kind, ns, name = key
-            if kind == "WorkUnit":
-                tl = self.syncer.metrics.timeline(tenant, ns, name)
-                if tl.dws_done == 0.0:
-                    tl.dws_done = done
-        if fast_items:
-            # batch the bookkeeping too: one lock round each instead of a
-            # limiter + two metric + one queue lock round PER KEY
-            self.limiter.forget_many(fast_items)
-            self.metrics.inc("reconcile_total", float(len(fast_items)),
-                             controller=self.name)
-            self.metrics.observe_n("reconcile_seconds", dur / len(items),
-                                   n=len(fast_items), controller=self.name)
-            self.queue.done_batch(fast_items)
-        for key in slow:
-            self._reconcile_one((tenant, key))
 
 
 class _ScanController(Controller):
@@ -261,12 +162,14 @@ class _ScanController(Controller):
 
 
 class Syncer:
-    """Facade over the downward shard / upward / scan controllers.
+    """Facade over the downward and upward pipelines and the scan controller.
 
-    Public API is unchanged from the single-queue implementation; ``shards``
-    and ``downward_batch`` add horizontal scale. Controllers are exposed via
-    ``.controllers`` so a cluster-wide ControllerManager can own them; the
-    ``start()``/``stop()`` methods remain for standalone use.
+    ``shards``/``downward_batch`` and ``upward_shards``/``upward_batch``
+    size each direction's fleet (``upward_batch=1`` is the per-item upward
+    path). Controllers are exposed via ``.controllers`` (downward shards,
+    then upward shards, then the scan) so a cluster-wide ControllerManager
+    can own them; the ``start()``/``stop()`` methods remain for standalone
+    use.
     """
 
     def __init__(self, super_api: APIServer, *,
@@ -274,14 +177,12 @@ class Syncer:
                  upward_workers: int = 100,
                  fair_queuing: bool = True,
                  scan_interval: float = 60.0,
-                 batch_upward: bool = True,
                  shards: int = 1,
                  downward_batch: int = 1,
                  upward_shards: Optional[int] = None,
                  upward_batch: int = 16,
                  record_events: bool = True,
                  event_ttl: float = 3600.0,
-                 ring_vnodes: int = 64,
                  executor: Optional[Any] = None,
                  informer_cache_budget: Optional[int] = None,
                  tracer: Optional[Any] = None):
@@ -303,19 +204,9 @@ class Syncer:
         # shared CooperativeExecutor: informer pumps, workers, and the scan
         # run as tasks on its bounded pool; None = legacy one-thread-per-loop
         self.executor = executor
-        # optional owning ControllerManager: resize_shards keeps its
-        # controller list in sync (health map + stop cover resized shards)
+        # optional owning ControllerManager: resizes keep its controller
+        # list in sync (health map + stop cover resized shards)
         self.manager: Optional[Any] = None
-        self.downward_workers = downward_workers
-        self.upward_workers = upward_workers
-        self.fair_queuing = fair_queuing
-        self.scan_interval = scan_interval
-        self.batch_upward = batch_upward
-        self.num_shards = max(1, int(shards))
-        self.downward_batch = max(1, int(downward_batch))
-        self.upward_batch = max(1, int(upward_batch))
-        self.ring_vnodes = max(1, int(ring_vnodes))
-        self.ring = ShardRing(self.num_shards, self.ring_vnodes)
         self._resize_lock = threading.Lock()
         self.metrics = SyncerMetrics()
         # k8s-style event TTL: the periodic scan expires Events whose
@@ -328,26 +219,30 @@ class Syncer:
         self._ns_map: Dict[str, Tuple[str, str]] = {}
         self._ns_lock = threading.Lock()
 
-        registry = MetricsRegistry()
-        per_shard = max(1, downward_workers // self.num_shards)
-        self.shard_controllers: List[_DownwardShard] = [
-            _DownwardShard(self, i, workers=per_shard, fair=fair_queuing,
-                           batch_size=self.downward_batch)
-            for i in range(self.num_shards)]
-        # upward fleet: defaults to the downward shard count, with the
-        # upward worker budget split across shards; batch_upward=False keeps
-        # the per-item path (the benchmark baseline)
-        self.upward = UpwardPipeline(
-            self,
-            shards=(upward_shards if upward_shards is not None
-                    else self.num_shards),
+        self.down = ShardedPipeline(
+            self, tag="dws", queue_name="downward", shards=shards,
+            total_workers=downward_workers, fair=fair_queuing,
+            batch_size=downward_batch,
+            # informer-cache races surface as Conflict/AlreadyExists
+            retry_on=(ConflictError, AlreadyExistsError, RetryLater),
+            sync_one=self._reconcile_down,
+            sync_fast=self._reconcile_down_fast,
+            stamp_dequeue=self._stamp_dws_dequeue, stamp_done=_stamp_dws_done,
+            on_migrate=self._hand_over_informers)
+        # the upward fleet defaults to the downward shard count
+        up = UpwardSync(self)
+        self.up = ShardedPipeline(
+            self, tag="uws", queue_name="upward",
+            shards=upward_shards if upward_shards is not None else shards,
             total_workers=upward_workers, fair=fair_queuing,
-            batch_size=self.upward_batch if batch_upward else 1,
-            ring_vnodes=self.ring_vnodes)
+            batch_size=upward_batch, retry_on=(ConflictError, RetryLater),
+            sync_one=up.reconcile_one, sync_fast=up.reconcile_fast,
+            stamp_dequeue=up.stamp_dequeue, stamp_done=up.stamp_done)
         self.controllers: List[Controller] = (
-            list(self.shard_controllers) + list(self.upward.controllers))
+            list(self.down.shards) + list(self.up.shards))
         if scan_interval > 0:
             self.controllers.append(_ScanController(self, scan_interval))
+        registry = MetricsRegistry()
         for c in self.controllers:
             c.metrics = registry
             c.executor = executor
@@ -365,50 +260,28 @@ class Syncer:
                 handler = self._node_handler
             elif kind in upward:
                 handler = self._super_handler(kind)
-            self._super_informers[kind] = self.up_controller.add_informer(
+            self._super_informers[kind] = self.up.shards[0].add_informer(
                 self.super_api, kind, handler=handler, name=f"super/{kind}")
 
     # ------------------------------------------------------------------ setup
 
     @property
-    def up_controller(self) -> Controller:
-        """Upward shard 0 (back-compat handle; also the shared registry
-        holder — every syncer controller carries the same ``metrics``)."""
-        return self.upward.controllers[0]
+    def num_shards(self) -> int:
+        return self.down.num_shards
 
     @property
     def num_upward_shards(self) -> int:
-        return self.upward.num_shards
-
-    @property
-    def upward_controllers(self) -> List[Controller]:
-        return list(self.upward.controllers)
-
-    @property
-    def up_queue(self) -> FairWorkQueue:
-        """Upward shard 0's queue (the only one when ``upward_shards == 1``)."""
-        return self.up_controller.queue
-
-    @property
-    def down_queue(self) -> FairWorkQueue:
-        """Shard 0's queue (the only queue when ``shards == 1``)."""
-        return self.shard_controllers[0].queue
-
-    def shard_for(self, tenant_uid: str) -> int:
-        return self.ring.shard_for(tenant_uid)
+        return self.up.num_shards
 
     def register_tenant(self, plane: TenantControlPlane, vc_uid: str = "") -> str:
         uid = vc_uid or plane.name
         prefix = ns_prefix(plane.name, uid)
+        reg = TenantRegistration(plane, prefix, uid)
         with self._resize_lock:
-            shard = self.shard_controllers[self.ring.shard_for(uid)]
-            up_shard = self.upward.shard_for_uid(uid)
-            reg = TenantRegistration(plane, prefix, shard, uid,
-                                     upward_shard=up_shard)
+            shard = self.down.register(plane.name, uid, plane.weight)
+            self.up.register(plane.name, uid, plane.weight)
             with self._tenants_lock:
                 self.tenants[plane.name] = reg
-            shard.queue.register_tenant(plane.name, plane.weight)
-            up_shard.queue.register_tenant(plane.name, plane.weight)
             # Declare ALL informers into reg.informers BEFORE starting any:
             # a started informer's initial replay enqueues keys immediately,
             # and a worker reconciling one must find every reg.informers
@@ -424,18 +297,18 @@ class Syncer:
         return prefix
 
     def unregister_tenant(self, tenant: str) -> None:
-        # under the resize lock: a concurrent resize_shards must not migrate
+        # under the resize lock: a concurrent resize must not migrate
         # (re-register + re-enqueue) a tenant that is being torn down
         with self._resize_lock:
             with self._tenants_lock:
                 reg = self.tenants.pop(tenant, None)
             if reg is None:
                 return
+            shard = self.down.owner[tenant]
             for inf in reg.informers.values():
-                reg.shard.remove_informer(inf)
-            reg.shard.queue.unregister_tenant(tenant)
-            reg.upward_shard.queue.drain_tenant(tenant)
-            reg.upward_shard.queue.unregister_tenant(tenant)
+                shard.remove_informer(inf)
+            self.down.unregister(tenant)
+            self.up.unregister(tenant)
         # remove the tenant's synced objects from the super cluster
         # (match by the tenant's namespace prefix — the registration is
         # already popped, so the reverse map may not resolve anymore).
@@ -466,17 +339,15 @@ class Syncer:
     @property
     def meter(self) -> Optional[Any]:
         """Optional :class:`~repro.core.metering.UsageMeter`. Assigning
-        propagates to every live shard queue (downward + upward); shards
-        created by a later resize inherit it at construction."""
+        propagates to every shard queue of both pipelines, including shards
+        a later resize adds."""
         return self._meter
 
     @meter.setter
     def meter(self, m: Optional[Any]) -> None:
         self._meter = m
-        for c in self.shard_controllers:
-            c.queue.meter = m
-        for uc in self.upward.controllers:
-            uc.queue.meter = m
+        self.down.meter = m
+        self.up.meter = m
 
     # --------------------------------------------------------------- resizing
 
@@ -504,102 +375,39 @@ class Syncer:
         added/removed here are also added/removed there, so the manager's
         health map and stop cover the resized fleet.
         """
-        n = max(1, int(n))
-        if not self._resize_lock.acquire(blocking=block):
-            return None
-        try:
-            return self._resize_shards_locked(n)
-        finally:
-            self._resize_lock.release()
+        return self._resize(self.down, n, block)
 
     def resize_upward_shards(self, n: int, *,
                              block: bool = True) -> Optional[Dict[str, int]]:
-        """Live-resize the UPWARD shard fleet to ``n`` shards.
+        """Live-resize the UPWARD shard fleet to ``n`` shards, under the
+        same contract as :meth:`resize_shards`. Upward shards carry no
+        per-tenant informers (super informers are shared and live on shard
+        0, which never retires), so migration is queue-only."""
+        return self._resize(self.up, n, block)
 
-        Same contract as :meth:`resize_shards` — consistent-hash ring
-        (~1/N tenants move), WRR weights preserved, pending keys drained to
-        the destination queue, idempotent no-op ``{}`` at the current count,
-        ``block=False`` returns ``None`` on a contended resize lock (the
-        autoscaler's third actuator runs on a pool thread). Upward shards
-        carry no per-tenant informers (super informers are shared and live
-        on shard 0, which never retires), so migration is queue-only.
-        """
+    def _resize(self, pipe: ShardedPipeline, n: int,
+                block: bool) -> Optional[Dict[str, int]]:
         n = max(1, int(n))
         if not self._resize_lock.acquire(blocking=block):
             return None
         try:
-            return self.upward.resize_locked(n)
+            return pipe.resize_locked(n)
         finally:
             self._resize_lock.release()
 
-    def _resize_shards_locked(self, n: int) -> Dict[str, int]:
-        if n == self.num_shards:
-            return {}
-        registry = self.up_controller.metrics
-        running = any(c.running for c in self.shard_controllers)
-        # new shards match the existing per-shard worker count so the
-        # fleet stays uniform (growing the fleet grows total capacity;
-        # sizing new shards to downward_workers // n would leave old
-        # shards with several times the workers of their peers)
-        per_shard = self.shard_controllers[0].workers
-        while len(self.shard_controllers) < n:
-            i = len(self.shard_controllers)
-            c = _DownwardShard(self, i, workers=per_shard,
-                               fair=self.fair_queuing,
-                               batch_size=self.downward_batch)
-            c.metrics = registry
-            c.executor = self.executor
-            self.shard_controllers.append(c)
-            self.controllers.append(c)
-            if running:
-                c.start()   # must run before tenants route onto it
-            if self.manager is not None:
-                self.manager.add(c)   # start() above is idempotent
-        new_ring = ShardRing(n, self.ring_vnodes)
-        with self._tenants_lock:
-            regs = list(self.tenants.values())
-        moved: Dict[str, int] = {}
-        for reg in regs:
-            target = new_ring.shard_for(reg.uid)
-            if target == reg.shard.shard_id:
-                continue
-            self._migrate_tenant(reg, self.shard_controllers[target])
-            moved[reg.plane.name] = target
-        self.ring = new_ring
-        self.num_shards = n
-        if len(self.shard_controllers) > n:   # shrink: now-empty shards
-            for c in self.shard_controllers[n:]:
-                c.stop()
-                self.controllers.remove(c)
-                if self.manager is not None:
-                    self.manager.remove(c)
-            del self.shard_controllers[n:]
-        return moved
-
-    def _migrate_tenant(self, reg: TenantRegistration,
-                        new_shard: _DownwardShard) -> None:
-        old_shard = reg.shard
-        tenant = reg.plane.name
-        new_shard.queue.register_tenant(tenant, reg.plane.weight)
-        reg.shard = new_shard       # event handlers resolve the queue via reg
-        pending = old_shard.queue.drain_tenant(tenant)
-        old_shard.queue.unregister_tenant(tenant)
-        for key in pending:
-            new_shard.queue.add(tenant, key)
-        for inf in reg.informers.values():
-            old_shard.detach_informer(inf)
-            new_shard.attach_informer(inf)
-        # A handler that read reg.shard just before the swap may have
-        # added to the old queue after the drain — auto-re-registering the
-        # tenant there as a ghost. The handler's re-check routes the item
-        # to the new queue too (dedup makes the double add harmless), so
-        # this second drain+unregister only clears the ghost entry.
-        old_shard.queue.drain_tenant(tenant)
-        old_shard.queue.unregister_tenant(tenant)
+    def _hand_over_informers(self, tenant: str, old: SyncShard,
+                             new: SyncShard) -> None:
+        """Downward migration hook: the tenant's informers change owning
+        shard without their reflectors stopping."""
+        for inf in self.tenants[tenant].informers.values():
+            old.detach_informer(inf)
+            new.attach_informer(inf)
 
     # ------------------------------------------------------------ event handlers
 
     def _tenant_handler(self, tenant: str, kind: str):
+        enqueue = self.down.enqueue
+
         def handler(ev_type: str, obj: Any) -> None:
             ns, name = obj.metadata.namespace, obj.metadata.name
             if kind == "WorkUnit" and ev_type == ADDED:
@@ -607,36 +415,30 @@ class Syncer:
                 if tl.dws_enqueue == 0.0:
                     tl.tenant_create = obj.metadata.creation_timestamp
                     tl.dws_enqueue = time.time()
-            # Resolve the owning shard at event time, not at registration:
-            # resize_shards may have migrated the tenant since. Lock-free
-            # dict read (GIL-atomic) — this is the per-event hot path.
-            # If a migration races the add (the old queue may already be
-            # drained or even shut down), re-add on the new shard; the
-            # destination queue dedups, so a double add is harmless.
-            while True:
-                reg = self.tenants.get(tenant)
-                if reg is None:
-                    return
-                shard = reg.shard
-                shard.queue.add(tenant, (kind, ns, name))
-                if reg.shard is shard:
-                    return
+            # the owning shard is resolved at event time, not at
+            # registration: a resize may have migrated the tenant since
+            enqueue(tenant, (kind, ns, name))
         return handler
 
     def _super_handler(self, kind: str):
+        enqueue = self.up.enqueue
+
         def handler(ev_type: str, obj: Any) -> None:
-            self.upward.enqueue(kind, obj.metadata.namespace,
-                                obj.metadata.name)
-            if kind == "WorkUnit":
-                t = self._resolve_super_ns(obj.metadata.namespace)
-                if t is not None and t[0]:
-                    tl = self.metrics.timeline(t[0], t[1], obj.metadata.name)
-                    if tl.uws_enqueue == 0.0:
-                        tl.uws_enqueue = time.time()
-                    if (tl.super_ready == 0.0 and obj.kind == "WorkUnit"
-                            and obj.status.phase == "Ready"):
-                        tl.super_ready = time.time()
-                        tl.uws_enqueue = tl.super_ready
+            ns, name = obj.metadata.namespace, obj.metadata.name
+            # unresolvable namespaces (cluster-scoped events, foreign
+            # objects) belong to no tenant
+            t = self._resolve_super_ns(ns)
+            if t is None:
+                return
+            enqueue(t[0], (kind, ns, name))
+            if kind == "WorkUnit" and t[0]:
+                tl = self.metrics.timeline(t[0], t[1], name)
+                if tl.uws_enqueue == 0.0:
+                    tl.uws_enqueue = time.time()
+                if (tl.super_ready == 0.0 and obj.kind == "WorkUnit"
+                        and obj.status.phase == "Ready"):
+                    tl.super_ready = time.time()
+                    tl.uws_enqueue = tl.super_ready
         return handler
 
     def _node_handler(self, ev_type: str, node: Any) -> None:
@@ -646,6 +448,16 @@ class Syncer:
             self.vnodes.broadcast_heartbeat(tenants, node)
 
     # ------------------------------------------------------------- reconcilers
+
+    def _stamp_dws_dequeue(self, tenant: str, key: DownItem,
+                           now: Optional[float] = None) -> Optional[UnitTimeline]:
+        kind, ns, name = key
+        if kind != "WorkUnit":
+            return None
+        tl = self.metrics.timeline(tenant, ns, name)
+        if tl.dws_dequeue == 0.0:
+            tl.dws_dequeue = now or time.time()
+        return tl
 
     def _reconcile_down(self, tenant: str, kind: str, ns: str, name: str,
                         api: Optional[Any] = None) -> None:
@@ -804,12 +616,9 @@ class Syncer:
                 to_create.append(
                     self._project_down(tenant_obj, tenant, ns, super_ns))
                 create_keys.append(key)
-                if tr is not None:
-                    tp = tenant_obj.metadata.annotations.get(TRACEPARENT_KEY)
-                    if tp and sampled_carrier(tp):
-                        traced[key] = tenant_obj
             elif _spec_equal(tenant_obj, cached):
                 fast.append(key)            # echo: two-side states match
+                continue
             else:                           # spec update: batched write
                 proj = self._project_down(tenant_obj, tenant, ns, super_ns)
                 proj.metadata.uid = cached.metadata.uid
@@ -818,10 +627,10 @@ class Syncer:
                     proj.status = deepcopy_obj(cached.status)  # super-owned
                 to_update.append(proj)
                 update_keys.append(key)
-                if tr is not None:
-                    tp = tenant_obj.metadata.annotations.get(TRACEPARENT_KEY)
-                    if tp and sampled_carrier(tp):
-                        traced[key] = tenant_obj
+            if tr is not None:
+                tp = tenant_obj.metadata.annotations.get(TRACEPARENT_KEY)
+                if tp and sampled_carrier(tp):
+                    traced[key] = tenant_obj
         m = self._meter
 
         def route_write(keys_projs: List[Tuple[DownItem, Any]],
@@ -915,19 +724,19 @@ class Syncer:
                     sobj = super_by_key.get((super_ns, name))
                     if sobj is None or not _spec_equal(
                             self._project_down(tobj, tenant, ns, super_ns), sobj):
-                        reg.shard.queue.add(tenant, (kind, ns, name))
+                        self.down.enqueue(tenant, (kind, ns, name))
                         fixes += 1
                     elif (kind in SYNCED_KINDS_UPWARD and hasattr(tobj, "status")
                           and not _status_equal(tobj.status, sobj.status,
                                                 ignore_node=True)):
-                        self.upward.enqueue(kind, super_ns, name)
+                        self.up.enqueue(tenant, (kind, super_ns, name))
                         fixes += 1
                     seen_super.add((super_ns, name))
                 # orphans in super (tenant object gone but super copy remains)
                 for sobj, tenant_ns in orphans_by_tenant.get(tenant, []):
                     if (sobj.metadata.namespace,
                             sobj.metadata.name) not in seen_super:
-                        reg.shard.queue.add(
+                        self.down.enqueue(
                             tenant, (kind, tenant_ns, sobj.metadata.name))
                         fixes += 1
         self._expire_events()
@@ -1027,7 +836,7 @@ class Syncer:
 
     def registry_snapshot(self) -> Dict[str, Any]:
         """Runtime MetricsRegistry snapshot for the syncer's controllers."""
-        return self.up_controller.metrics.snapshot()
+        return self.controllers[0].metrics.snapshot()
 
     def memory_estimate(self) -> int:
         total = 0
@@ -1039,6 +848,11 @@ class Syncer:
         for inf in self._super_informers.values():
             total += inf.cache.nbytes_estimate()
         return total
+
+
+def _stamp_dws_done(tl: UnitTimeline, now: Optional[float] = None) -> None:
+    if tl.dws_done == 0.0:
+        tl.dws_done = now or time.time()
 
 
 # the comparison helpers now live in objects.py (the upward pipeline needs

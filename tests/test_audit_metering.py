@@ -146,7 +146,7 @@ def metered_rig():
     super_api = APIServer("super")
     syncer = Syncer(super_api, downward_workers=4, upward_workers=4,
                     scan_interval=0.0, shards=2, downward_batch=8,
-                    upward_shards=2, batch_upward=True, upward_batch=8)
+                    upward_shards=2, upward_batch=8)
     syncer.meter = meter
     planes = [TenantControlPlane(f"t{i:02d}") for i in range(3)]
     for i, p in enumerate(planes):
@@ -211,7 +211,7 @@ def test_upward_batched_attribution_exact():
     super_api = APIServer("super")
     syncer = Syncer(super_api, downward_workers=4, upward_workers=4,
                     scan_interval=0.0, shards=2, downward_batch=8,
-                    upward_shards=2, batch_upward=True, upward_batch=8)
+                    upward_shards=2, upward_batch=8)
     syncer.meter = meter
     planes = [TenantControlPlane(f"t{i:02d}") for i in range(3)]
     for i, p in enumerate(planes):
@@ -368,7 +368,7 @@ def test_autotune_dampens_noisy_tenant_weights():
     scaler = Autoscaler(syncer, None, policy=policy, interval=3600)
     scaler.meter = meter
     try:
-        q = syncer.shard_controllers[0].queue
+        q = syncer.down.shards[0].queue
         # equal wait profiles: every tenant's boost factor is exactly 1.0
         for p in planes:
             q.per_tenant_wait.setdefault(p.name, []).extend([0.2] * 10)
@@ -384,7 +384,7 @@ def test_autotune_dampens_noisy_tenant_weights():
         assert q._weights.get("t2", 4) == 4
         # surfaced for /healthz via autoscaler state
         assert "t0" in scaler.state()["noisy_neighbors"]
-        reg = syncer.up_controller.metrics
+        reg = syncer.controllers[0].metrics
         assert reg.counter("autoscaler_noisy_dampened", tenant="t0") >= 1
     finally:
         scaler.stop()
@@ -404,7 +404,7 @@ def test_autotune_without_meter_unchanged():
     syncer.start()
     scaler = Autoscaler(syncer, None, policy=ScalingPolicy(), interval=3600)
     try:
-        q = syncer.shard_controllers[0].queue
+        q = syncer.down.shards[0].queue
         for p in planes:
             q.per_tenant_wait.setdefault(p.name, []).extend([0.2] * 10)
         changed = scaler._autotune_weights()

@@ -179,7 +179,7 @@ def test_autoscaler_grows_under_burst_and_shrinks_idle_no_lost_keys():
         assert any(d["actuator"] == "shards" for d in downs)
         assert any(d["actuator"] == "executor_pool" for d in downs)
         # decisions are visible in the registry
-        reg = syncer.up_controller.metrics
+        reg = syncer.controllers[0].metrics
         assert reg.counter("autoscaler_scale_total", controller="autoscaler",
                            actuator="shards", direction="up") >= 1
         assert reg.counter("autoscaler_scale_total", controller="autoscaler",
@@ -270,7 +270,7 @@ def test_upward_actuator_grows_on_status_storm_and_shrinks_idle():
     super_api = APIServer("super")
     syncer = Syncer(super_api, downward_workers=4, upward_workers=4,
                     scan_interval=0.0, shards=1, downward_batch=4,
-                    upward_shards=1, batch_upward=True, executor=ex)
+                    upward_shards=1, executor=ex)
     planes = [TenantControlPlane(f"t{i:02d}") for i in range(6)]
     for i, p in enumerate(planes):
         syncer.register_tenant(p, f"uid-{i:02d}")
@@ -328,7 +328,7 @@ def test_upward_actuator_grows_on_status_storm_and_shrinks_idle():
         assert ups, "upward actuator never grew the fleet"
         # idle cooldown: the upward fleet returns to its floor
         assert wait_for(lambda: syncer.num_upward_shards == 1, timeout=30.0)
-        reg = syncer.up_controller.metrics
+        reg = syncer.controllers[0].metrics
         assert reg.counter("autoscaler_scale_total", controller="autoscaler",
                            actuator="upward_shards", direction="up") >= 1
     finally:
@@ -347,7 +347,7 @@ def test_weight_autotune_boosts_waiting_tenant_within_bounds():
     ex, super_api, syncer, planes = _rig(tenants=2)
     scaler = Autoscaler(syncer, ex, policy=_fast_policy(), interval=3600)
     try:
-        q = syncer.shard_controllers[0].queue
+        q = syncer.down.shards[0].queue
         slow, fastt = planes[0].name, planes[1].name
         # synthetic wait samples: tenant 0 waits 8x longer than tenant 1
         # at EQUAL throughput (same sample count) -> genuinely under-served
@@ -381,7 +381,7 @@ def test_weight_autotune_does_not_reward_queue_flooder():
     ex, super_api, syncer, planes = _rig(tenants=2)
     scaler = Autoscaler(syncer, ex, policy=_fast_policy(), interval=3600)
     try:
-        q = syncer.shard_controllers[0].queue
+        q = syncer.down.shards[0].queue
         flooder, quiet = planes[0].name, planes[1].name
         base = syncer.tenants[flooder].plane.weight
         # flooder: 8x the throughput AND 8x the wait (self-inflicted)
@@ -454,11 +454,12 @@ def test_resize_shards_concurrent_callers_serialize_no_lost_keys():
         assert syncer.resize_shards(2) is not None
         assert syncer.resize_shards(2) == {}        # idempotent no-op
         assert syncer.num_shards == 2
-        assert len(syncer.shard_controllers) == 2
-        assert [c.shard_id for c in syncer.shard_controllers] == [0, 1]
+        assert len(syncer.down.shards) == 2
+        assert [c.shard_id for c in syncer.down.shards] == [0, 1]
         for reg in syncer.tenants.values():
-            assert reg.shard in syncer.shard_controllers
-            assert reg.shard.shard_id == syncer.ring.shard_for(reg.uid)
+            shard = syncer.down.owner[reg.plane.name]
+            assert shard in syncer.down.shards
+            assert shard.shard_id == syncer.down.ring.shard_for(reg.uid)
         total = len(planes) * 200
         assert wait_for(
             lambda: super_api.store.count("WorkUnit") >= total, timeout=60.0)

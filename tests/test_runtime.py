@@ -293,10 +293,10 @@ def test_syncer_assigns_tenant_to_stable_shard():
         for i, p in enumerate(planes):
             syncer.register_tenant(p, f"uid-{i}")
         for i, p in enumerate(planes):
-            reg = syncer.tenants[p.name]
-            assert reg.shard.shard_id == syncer.shard_for(f"uid-{i}")
+            shard = syncer.down.owner[p.name]
+            assert shard.shard_id == syncer.down.ring.shard_for(f"uid-{i}")
             # a second syncer with the same shard count agrees
-            assert reg.shard.shard_id == shard_for(f"uid-{i}", 4)
+            assert shard.shard_id == shard_for(f"uid-{i}", 4)
     finally:
         syncer.stop()
         api.close()

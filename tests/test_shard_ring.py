@@ -73,7 +73,7 @@ def test_syncer_placement_survives_restart():
                 p = TenantControlPlane(f"t{i}")
                 syncer.register_tenant(p, f"uid-{i}")
             placements.append(
-                {t: r.shard.shard_id for t, r in syncer.tenants.items()})
+                {t: s.shard_id for t, s in syncer.down.owner.items()})
         finally:
             syncer.stop()
             api.close()
@@ -97,75 +97,133 @@ def live_rig():
     super_api.close()
 
 
-def test_resize_moves_at_most_a_fraction_and_keeps_weights(live_rig):
+# The four resize tests run against each direction's pipeline: downward
+# items are tenant creates that must reach the super cluster, upward items
+# are super-side status changes that must reach the tenant planes.
+
+@pytest.fixture(params=["down", "up"])
+def direction(request):
+    return request.param
+
+
+def _fleet(syncer, direction):
+    """The direction's pipeline and its resize call."""
+    if direction == "down":
+        return syncer.down, syncer.resize_shards
+    return syncer.up, syncer.resize_upward_shards
+
+
+def _ready(u):
+    u.status.phase = "Ready"
+
+
+def _push(super_api, syncer, planes, direction, names):
+    """One item per tenant and name: a tenant create downward; upward, a
+    Ready status on the (already synced-down) super copy."""
+    for p in planes:
+        if direction == "down":
+            for n in names:
+                p.api.create(mk_unit(n))
+        else:
+            ns = f"{syncer.tenants[p.name].prefix}-default"
+            for n in names:
+                super_api.update_status("WorkUnit", ns, n, _ready)
+
+
+def _landed(super_api, syncer, planes, direction, names):
+    for p in planes:
+        if direction == "down":
+            ns = f"{syncer.tenants[p.name].prefix}-default"
+            synced = {u.metadata.name for u in super_api.list("WorkUnit", ns)}
+            if not synced >= set(names):
+                return False
+        elif not all(p.api.get("WorkUnit", "default", n).status.phase
+                     == "Ready" for n in names):
+            return False
+    return True
+
+
+def _prepare(super_api, syncer, planes, direction, names):
+    """Upward items need their units synced down first."""
+    if direction == "up":
+        _push(super_api, syncer, planes, "down", names)
+        assert wait_for(
+            lambda: _landed(super_api, syncer, planes, "down", names),
+            timeout=30)
+
+
+def test_resize_moves_at_most_a_fraction_and_keeps_weights(live_rig,
+                                                           direction):
     super_api, syncer, planes = live_rig
-    before = {p.name: syncer.tenants[p.name].shard.shard_id for p in planes}
-    moved = syncer.resize_shards(3)
-    assert syncer.num_shards == 3
-    assert len(syncer.shard_controllers) == 3
+    pipe, resize = _fleet(syncer, direction)
+    other = syncer.up if pipe is syncer.down else syncer.down
+    before = {p.name: pipe.owner[p.name].shard_id for p in planes}
+    moved = resize(3)
+    assert pipe.num_shards == 3
+    assert len(pipe.shards) == 3
+    assert other.num_shards == 2          # the other direction is untouched
     # ~1/N remap: at most half the tenants move for 2 -> 3 shards (expected
     # fraction is 1/3; allow sampling slack on 12 tenants)
     assert len(moved) <= len(planes) // 2
     for tenant, new_shard in moved.items():
         assert new_shard != before[tenant]
-        reg = syncer.tenants[tenant]
-        assert reg.shard.shard_id == new_shard
+        shard = pipe.owner[tenant]
+        assert shard.shard_id == new_shard
         # WRR weight preserved on the destination queue
-        assert reg.shard.queue._weights[tenant] == reg.plane.weight
+        assert shard.queue._weights[tenant] == syncer.tenants[tenant].plane.weight
     # stayers keep their registration on the original queue
     for p in planes:
         if p.name not in moved:
-            assert p.name in syncer.tenants[p.name].shard.queue._weights
+            assert p.name in pipe.owner[p.name].queue._weights
 
 
-def test_resize_agrees_with_fresh_syncer_at_new_count(live_rig):
+def test_resize_agrees_with_fresh_syncer_at_new_count(live_rig, direction):
     super_api, syncer, planes = live_rig
-    syncer.resize_shards(3)
+    pipe, resize = _fleet(syncer, direction)
+    resize(3)
     for i, p in enumerate(planes):
-        assert (syncer.tenants[p.name].shard.shard_id
-                == shard_for(f"uid-{i}", 3))
+        assert pipe.owner[p.name].shard_id == shard_for(f"uid-{i}", 3)
 
 
-def test_resize_mid_burst_loses_no_items(live_rig):
+def test_resize_mid_burst_loses_no_items(live_rig, direction):
     """Items queued and in flight when the fleet grows must all still sync."""
     super_api, syncer, planes = live_rig
-    per_tenant = 40
-    for p in planes:
-        for j in range(per_tenant):
-            p.api.create(mk_unit(f"u{j:03d}"))
-    syncer.resize_shards(3)        # mid-burst: queues are non-empty
-    for p in planes:               # post-resize traffic follows the movers
-        for j in range(per_tenant, per_tenant + 5):
-            p.api.create(mk_unit(f"u{j:03d}"))
-    total = len(planes) * (per_tenant + 5)
+    _, resize = _fleet(syncer, direction)
+    burst = [f"u{j:03d}" for j in range(40)]
+    after = [f"u{j:03d}" for j in range(40, 45)]
+    _prepare(super_api, syncer, planes, direction, burst + after)
+    _push(super_api, syncer, planes, direction, burst)
+    resize(3)                      # mid-burst: queues are non-empty
+    # post-resize traffic follows the movers
+    _push(super_api, syncer, planes, direction, after)
     assert wait_for(
-        lambda: super_api.store.count("WorkUnit") == total, timeout=30), \
-        f"synced {super_api.store.count('WorkUnit')}/{total}"
+        lambda: _landed(super_api, syncer, planes, direction, burst + after),
+        timeout=30), f"{direction}: {super_api.store.count('WorkUnit')} units"
 
 
-def test_resize_shrink_drains_removed_shards(live_rig):
+def test_resize_shrink_drains_removed_shards(live_rig, direction):
     super_api, syncer, planes = live_rig
-    syncer.resize_shards(3)
-    for p in planes:
-        p.api.create(mk_unit("a"))
+    pipe, resize = _fleet(syncer, direction)
+    _prepare(super_api, syncer, planes, direction, ["a", "b"])
+    resize(3)
+    _push(super_api, syncer, planes, direction, ["a"])
     assert wait_for(
-        lambda: super_api.store.count("WorkUnit") == len(planes))
-    syncer.resize_shards(1)
-    assert syncer.num_shards == 1
-    assert len(syncer.shard_controllers) == 1
+        lambda: _landed(super_api, syncer, planes, direction, ["a"]))
+    resize(1)
+    assert pipe.num_shards == 1
+    assert len(pipe.shards) == 1
     # every tenant must now live on shard 0
-    assert all(r.shard.shard_id == 0 for r in syncer.tenants.values())
-    for p in planes:
-        p.api.create(mk_unit("b"))
+    assert all(s.shard_id == 0 for s in pipe.owner.values())
+    _push(super_api, syncer, planes, direction, ["b"])
     assert wait_for(
-        lambda: super_api.store.count("WorkUnit") == 2 * len(planes))
+        lambda: _landed(super_api, syncer, planes, direction, ["a", "b"]))
 
 
 # ------------------------------------------------------- per-shard API clients
 
 def test_each_shard_gets_its_own_super_client(live_rig):
     super_api, syncer, planes = live_rig
-    clients = [c.api for c in syncer.shard_controllers]
+    clients = [c.api for c in syncer.down.shards]
     assert len({id(c) for c in clients}) == len(clients)
     for c in clients:
         assert c is not super_api

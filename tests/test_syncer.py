@@ -173,12 +173,11 @@ def test_sharded_upward_sync_routes_back_to_owner(sharded_rig):
 
 def test_sharded_tenants_partition_covers_multiple_shards(sharded_rig):
     super_api, syncer, planes, prefixes = sharded_rig
-    shard_ids = {syncer.tenants[p.name].shard.shard_id for p in planes}
+    shard_ids = {syncer.down.owner[p.name].shard_id for p in planes}
     assert len(shard_ids) > 1          # 8 tenants over 4 shards: must spread
     # tenants on the same shard share that shard's fair queue registration
     for p in planes:
-        reg = syncer.tenants[p.name]
-        assert p.name in reg.shard.queue._weights
+        assert p.name in syncer.down.owner[p.name].queue._weights
 
 
 def test_sharded_scan_remediates_to_owning_shard(sharded_rig):
@@ -198,7 +197,7 @@ def test_sharded_burst_no_starvation(sharded_rig):
     # find two tenants on the same shard
     by_shard = {}
     for p in planes:
-        by_shard.setdefault(syncer.tenants[p.name].shard.shard_id, []).append(p)
+        by_shard.setdefault(syncer.down.owner[p.name].shard_id, []).append(p)
     cohabitants = next(v for v in by_shard.values() if len(v) >= 2)
     greedy, regular = cohabitants[0], cohabitants[1]
     for j in range(200):

@@ -77,7 +77,7 @@ def test_dedup_reduces_sync_work(burst_rig):
     is absorbed. (Label-only updates: no spec change reaches the super
     cluster, so this is pure sync-queue traffic.)"""
     fw, planes = burst_rig
-    q = fw.syncer.down_queue
+    q = fw.syncer.down.shards[0].queue
 
     def churn(plane):
         for j in range(25):

@@ -71,7 +71,7 @@ def rig():
     super_api = APIServer("super")
     syncer = Syncer(super_api, downward_workers=4, upward_workers=8,
                     scan_interval=0.0, shards=2, downward_batch=4,
-                    upward_shards=4, batch_upward=True, upward_batch=8)
+                    upward_shards=4, upward_batch=8)
     planes = [TenantControlPlane(f"t{i:02d}") for i in range(8)]
     prefixes = [syncer.register_tenant(p, f"uid-{i}")
                 for i, p in enumerate(planes)]
@@ -88,15 +88,15 @@ def rig():
 def test_upward_shards_partition_tenants(rig):
     super_api, syncer, planes, prefixes = rig
     assert syncer.num_upward_shards == 4
-    shard_ids = {syncer.tenants[p.name].upward_shard.shard_id for p in planes}
+    shard_ids = {syncer.up.owner[p.name].shard_id for p in planes}
     assert len(shard_ids) > 1          # 8 tenants over 4 shards: must spread
     for p in planes:
         reg = syncer.tenants[p.name]
-        assert p.name in reg.upward_shard.queue._weights
+        shard = syncer.up.owner[p.name]
+        assert p.name in shard.queue._weights
         # upward and downward placements are independent rings — but both
         # must agree with their own ring
-        assert (reg.upward_shard.shard_id
-                == syncer.upward.ring.shard_for(reg.uid))
+        assert shard.shard_id == syncer.up.ring.shard_for(reg.uid)
 
 
 def test_status_syncs_up_through_shards(rig):
@@ -177,7 +177,7 @@ def test_resize_upward_shards_live_migration(rig):
     moved = syncer.resize_upward_shards(6)
     assert isinstance(moved, dict)
     assert syncer.num_upward_shards == 6
-    assert len(syncer.upward.controllers) == 6
+    assert len(syncer.up.shards) == 6
     for pre in prefixes:
         ns = f"{pre}-default"
         for j in range(per_tenant):
@@ -190,9 +190,9 @@ def test_resize_upward_shards_live_migration(rig):
     assert syncer.num_shards == 2
     for p in planes:
         reg = syncer.tenants[p.name]
-        assert reg.upward_shard in syncer.upward.controllers
-        assert (reg.upward_shard.shard_id
-                == syncer.upward.ring.shard_for(reg.uid))
+        shard = syncer.up.owner[p.name]
+        assert shard in syncer.up.shards
+        assert shard.shard_id == syncer.up.ring.shard_for(reg.uid)
 
     def converged(p):
         units = p.api.list("WorkUnit", "default")
@@ -216,10 +216,10 @@ def test_resize_upward_idempotent_and_nonblocking():
 
 
 def test_per_item_mode_still_syncs():
-    """batch_upward=False: the per-item baseline path stays correct."""
+    """upward_batch=1: the per-item baseline path stays correct."""
     super_api = APIServer("super")
     syncer = Syncer(super_api, downward_workers=4, upward_workers=4,
-                    scan_interval=0.0, batch_upward=False)
+                    scan_interval=0.0, upward_batch=1)
     plane = TenantControlPlane("acme")
     prefix = syncer.register_tenant(plane, "uid-1")
     syncer.start()

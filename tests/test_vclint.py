@@ -143,6 +143,34 @@ def test_vcl002_sleep_reachable_from_reconcile():
         in findings[0].message
 
 
+def test_vcl002_sleep_reachable_through_pipeline_hook():
+    # a method or function handed to a ShardedPipeline runs inside its
+    # shards' reconcile, in whatever module it is defined
+    src = """
+        import time
+
+        def stamp(tl):
+            time.sleep(0.5)
+
+        class Worker:
+            def work(self, item):
+                time.sleep(0.5)
+
+        class Owner:
+            def __init__(self):
+                w = Worker()
+                self.pipe = ShardedPipeline(self, sync_one=w.work,
+                                            stamp_done=stamp,
+                                            sync_fast=self._fast)
+
+            def _fast(self, items):
+                time.sleep(0.5)
+    """
+    findings = check(BlockingCallRule, src, relpath="core/other.py")
+    assert sorted(f.qualname for f in findings) == [
+        "Owner._fast", "Worker.work", "stamp"]
+
+
 def test_vcl002_entry_modules_only():
     # same code outside the five concurrency modules: not an entry
     assert check(BlockingCallRule, BLOCKING_RECONCILE,

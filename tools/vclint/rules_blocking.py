@@ -4,8 +4,9 @@ Entry points are the control plane's cooperative quanta — the
 ``_pump`` / ``_worker_quantum`` / ``_scan_quantum`` / ``_run_quantum``
 functions plus ``reconcile`` / ``reconcile_batch`` / ``scan`` /
 ``scan_once`` / ``poll`` methods on ``Controller`` (and subclasses) and
-on classes in the five core concurrency modules. From each entry, the
-call graph is walked (best-effort resolution, virtual dispatch
+on classes in the five core concurrency modules, plus the methods and
+functions handed by reference to a ``ShardedPipeline`` (its shards call
+those hooks from ``reconcile``). From each entry, the call graph is walked (best-effort resolution, virtual dispatch
 included) and the following are flagged anywhere reachable:
 
 - ``time.sleep(x)`` with non-zero x;
@@ -39,6 +40,8 @@ ENTRY_MODULES = ("executor.py", "informer.py", "runtime.py", "syncer.py",
                  # from its reconcile/scan — blocking there stalls a quantum
                  "serving/engine.py", "serving/scheduler.py",
                  "serving/host.py")
+# constructors whose callable arguments run inside cooperative reconciles
+HOOK_CONSUMERS = {"ShardedPipeline"}
 POLL_GATED = {"get", "get_batch", "next", "poll"}
 JOIN_TYPES = {"Thread", "Timer", "Task"}
 WAIT_TYPES = {"Event", "Condition"}
@@ -171,7 +174,48 @@ class BlockingCallRule(Rule):
                 elif fn.name in ENTRY_METHOD_NAMES and ci is not None and (
                         in_core5 or ci.name in controllers):
                     out.append((qual, ci, fn))
+                out.extend(self._hook_entries(ci, fn))
         return out
+
+    def _hook_entries(self, ci: Optional[ClassInfo], fn: FuncDef
+                      ) -> List[Tuple[str, Optional[ClassInfo], FuncDef]]:
+        """Functions ``fn`` hands by reference to a HOOK_CONSUMERS
+        constructor (``sync_one=self._reconcile_down``, ``up.reconcile_one``,
+        a module function)."""
+        out: List[Tuple[str, Optional[ClassInfo], FuncDef]] = []
+        table: Optional[Dict[str, str]] = None
+        for node in walk_in_scope(fn):
+            if not (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Name)
+                    and node.func.id in HOOK_CONSUMERS):
+                continue
+            if table is None:
+                table = local_type_table(self.project, ci, fn)
+            for arg in list(node.args) + [kw.value for kw in node.keywords]:
+                for hci, hfn in self._callable_ref(ci, arg, table):
+                    qual = f"{hci.name}.{hfn.name}" if hci else hfn.name
+                    out.append((qual, hci, hfn))
+        return out
+
+    def _callable_ref(self, ci: Optional[ClassInfo], expr: ast.expr,
+                      table: Dict[str, str]
+                      ) -> List[Tuple[Optional[ClassInfo], FuncDef]]:
+        """The definitions a function reference (not a call) names."""
+        if isinstance(expr, ast.Name):
+            return [(None, f) for _, f in
+                    self.project.functions_by_name.get(expr.id, [])]
+        if not isinstance(expr, ast.Attribute):
+            return []
+        t = self.project._receiver_type(ci, expr.value, table)
+        if t == "self" and ci is not None:
+            owners = [ci]
+        elif t is not None:
+            owners = self.project.classes_by_name.get(
+                t.split("[")[0].split(".")[-1], [])
+        else:
+            return []
+        hits = [self.project.lookup_method(o, expr.attr) for o in owners]
+        return [h for h in hits if h is not None]
 
     def _flag(self, relpath: str, qualname: str, ci: Optional[ClassInfo],
               call: ast.Call, table: Dict[str, str], chain: str
